@@ -120,6 +120,8 @@ def main(argv=None) -> None:
     ap.add_argument("--list", action="store_true",
                     help="print the stage registry and exit")
     args = ap.parse_args(argv)
+    from repro.api import compile_cache
+    compile_cache.enable()
 
     stages = build_stages()
     if args.list:

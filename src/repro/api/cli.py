@@ -14,8 +14,8 @@ from __future__ import annotations
 
 import argparse
 
-from . import (PROTOCOLS, FaultPlan, engine_names, fit, serve,
-               workload_names)
+from . import (PROTOCOLS, FaultPlan, compile_cache, engine_names, fit,
+               serve, workload_names)
 from . import workloads as workloads_mod
 
 
@@ -63,6 +63,7 @@ def main(argv=None) -> None:
         print("engines:   ", ", ".join(engine_names()))
         print("objectives:", ", ".join(objective_names()))
         return
+    compile_cache.enable()
 
     plan = None
     if args.straggle_p is not None:
@@ -113,6 +114,7 @@ def serve_main(argv=None) -> None:
                     help="serve only the first Q eval rows")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     res = fit(args.workload, args.protocol, args.train_engine,
               key=args.seed, iters=args.iters, history=False)

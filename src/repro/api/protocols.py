@@ -35,7 +35,7 @@ import numpy as np
 
 from ..core import baselines, cost_model, secure_agg
 from ..core import objectives as objectives_mod
-from ..core.protocol import Copml
+from ..core.protocol import Copml, fused_mode
 from ..train import elastic
 from . import engine as engine_mod
 from . import faults as faults_mod
@@ -292,12 +292,14 @@ class CopmlProtocol(Protocol):
         self._drivers: dict = {}
 
     def driver(self, wl) -> Copml:
-        """The (cached) Copml instance for a workload -- caching keeps the
+        """The (cached) Copml instance for a workload and megakernel gate
+        (REPRO_FUSED_STEP, read once per instance) -- caching keeps the
         per-instance jit/scan caches warm across fit() calls."""
-        if wl not in self._drivers:
-            self._drivers[wl] = Copml(wl.cfg, wl.m, wl.d,
-                                      objective=wl.objective)
-        return self._drivers[wl]
+        key = (wl, fused_mode())
+        if key not in self._drivers:
+            self._drivers[key] = Copml(wl.cfg, wl.m, wl.d,
+                                       objective=wl.objective)
+        return self._drivers[key]
 
     def fault_threshold(self, wl) -> int:
         """R = (2r+1)(K+T-1)+1 honest on-time clients per step."""

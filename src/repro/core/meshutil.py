@@ -24,48 +24,18 @@ import numpy as np
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with explicit Auto axis types where supported.
-
-    jax.sharding.AxisType (and make_mesh's axis_types kwarg) only exist in
-    newer JAX; on older versions every axis is implicitly Auto, so omitting
-    the kwarg is semantically identical."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis Auto (GSPMD-annotated, not explicit
+    sharding), the mode `maybe_constrain`'s hints are written for."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
-
-
-def set_mesh(mesh):
-    """Context manager activating `mesh`: jax.set_mesh where it exists,
-    the legacy Mesh context (which is its own context manager and equally
-    enables bare-PartitionSpec sharding constraints) on older JAX."""
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh
-
-
-def _active_mesh():
-    """The ambient mesh, or None: get_abstract_mesh on new JAX, the
-    thread-resources physical mesh set by the Mesh context on old JAX."""
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        return getter()
-    from jax.interpreters import pxla
-    m = pxla.thread_resources.env.physical_mesh
-    return None if m.empty else m
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def maybe_constrain(x, *spec):
-    """with_sharding_constraint iff a usable mesh is active (set_mesh above).
+    """with_sharding_constraint iff a usable mesh is active (jax.set_mesh).
 
     Axes absent from the mesh or not dividing the dim are dropped, so the
     same code runs on a laptop and on the 512-chip production mesh."""
-    try:
-        mesh = _active_mesh()
-    except Exception:   # noqa: BLE001
-        return x
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or not mesh.shape:
         return x
     fixed = []

@@ -37,7 +37,6 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from . import (field, lagrange, meshutil, mpc, objectives, quantize, shamir,
@@ -85,6 +84,16 @@ class CopmlConfig:
         assert self.n_clients >= 2 * self.t + 1, "MPC mult needs N >= 2T+1"
         assert self.mag_bits + self.s_grad + 2 <= field.P_BITS, (
             "fixed-point budget exceeds field size")
+
+
+def fused_mode() -> str:
+    """The per-iteration schedule REPRO_FUSED_STEP selects:
+      "0"      -- phase-siloed reference path
+      "1"      -- fused one-dispatch step (ops.fused_step; Pallas if
+                  REPRO_USE_PALLAS, else the fused jnp composition)
+      "kernel" -- force the Pallas megakernel regardless of USE_PALLAS
+    """
+    return os.environ.get("REPRO_FUSED_STEP", "1")
 
 
 # Corruption offset added to an adversarial client's coded gradient.  It
@@ -178,13 +187,10 @@ class Copml:
         # field coefficients of ghat at output scale lg given input scale lz
         self.poly_coeffs = self.obj.field_coeffs(cfg)
         self._mul = mpc.mul_bh08 if cfg.mpc_mul == "bh08" else mpc.mul_bgw
-        # fused-megakernel gate, snapshotted per instance (api.fit builds a
-        # fresh Copml, so tests flipping the env var always take effect):
-        #   "0"      -- phase-siloed reference path
-        #   "1"      -- fused one-dispatch step (ops.fused_step; Pallas if
-        #               REPRO_USE_PALLAS, else the fused jnp composition)
-        #   "kernel" -- force the Pallas megakernel regardless of USE_PALLAS
-        self.fused_mode = os.environ.get("REPRO_FUSED_STEP", "1")
+        # the megakernel gate, snapshotted per instance (api.fit caches
+        # one Copml per (workload, gate), so flipping it between fits
+        # builds a new driver instead of reusing the old schedule)
+        self.fused_mode = fused_mode()
 
     # ------------------------------------------------------------------ setup
 
@@ -917,9 +923,9 @@ class Copml:
         n_fx = {"plan": 2, "plan_adv": 3}.get(fault_kind, 0)
         cl = P(axis)
         out_specs = (cl, P()) if history else cl
-        sm = shard_map(loop, mesh,
-                       in_specs=(cl, cl, cl, cl, cl, P()) + (P(),) * n_fx,
-                       out_specs=out_specs, check_rep=False)
+        sm = jax.shard_map(loop, mesh=mesh,
+                           in_specs=(cl, cl, cl, cl, cl, P()) + (P(),) * n_fx,
+                           out_specs=out_specs, check_vma=False)
         jfn = jax.jit(sm)
         pmat_j, wall_j = jnp.asarray(pmat), jnp.asarray(wall)
 

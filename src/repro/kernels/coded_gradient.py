@@ -6,22 +6,25 @@ both passes over a single VMEM-resident row-block of X~ halves HBM traffic --
 the op is memory-bound (arithmetic intensity ~ O(1) per X~ element for the
 matvec pair), so this is a ~2x win on the memory roofline term.
 
-Grid: one dimension over row blocks of X~; the (d,) output accumulator lives
-in VMEM and is revisited by every grid step.  Field arithmetic follows
-modmatmul.py: 7-bit limbs -> exact f32 MXU products -> int32 recombination.
+One kernel serves every caller: the COPML hot loop computes f for ALL N
+clients every iteration (each with its own coded slice X~_i and coded model
+w~_i), so an (N, m/bm) grid runs the whole round as ONE pallas_call -- one
+dispatch, one pipeline, w~_i resident in VMEM across a client's row blocks.
+The model is class-major, w~_i: (C, d), so both passes are GEMMs with the
+class width C in the MXU free dimension (C = 1 is the binary vector model,
+C > 1 the multi-class one-vs-rest matrix model):
 
-`coded_gradient_batched` adds a leading client dimension: the COPML hot loop
-computes f for ALL N clients every iteration (each with its own coded slice
-X~_i and coded model w~_i), so a (N, m/bm) grid runs the whole round as ONE
-pallas_call -- one dispatch, one pipeline, w~_i resident in VMEM across a
-client's row blocks -- instead of N single-client launches under an outer
-vmap.
+    z^T = W X_blk^T        (C, bm)   contraction over d, chunked by dc
+    g^T = ghat(z^T)        (C, bm)   unrolled Horner on the VPU
+    f  += g^T X_blk        (C, d)    contraction over bm
 
-`coded_gradient_matrix` is the class-batched form for MATRIX models
-(multi-class one-vs-rest): w~_i is (d, C), so both passes are real GEMMs
-(C columns in the MXU free dimension) on the same (N, m/bm) grid -- one
-launch computing X~^T ghat(X~ W) for every client and every class, instead
-of C matvec dispatches per client.
+Layout for Mosaic: d rides the 128-lane axis and C the sublanes, so a vector
+model is one lane-dense row instead of a 128x lane-padded column, and every
+block's last two dims are either (8, 128)-aligned or the array's own.  The
+gradient polynomial's coefficients are scalars read from SMEM.
+
+Field arithmetic follows modmatmul.py: 7-bit limbs -> exact f32 MXU products
+-> int32 recombination.
 """
 
 from __future__ import annotations
@@ -31,6 +34,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core import field
 
@@ -44,7 +48,7 @@ def _limb(x, i):
 
 
 def _limb_dot_mod(a, b, contract_a: int, contract_b: int):
-    """Field 'matmul' of int32 blocks a, b contracting the given dims.
+    """Field 'matmul' of 2-D int32 blocks a, b contracting the given dims.
 
     Contraction length must be <= 1024 (exact f32).  Returns int32 mod p.
     The 16 limb-pair MXU partials are grouped by weight class s = i+j in
@@ -65,176 +69,65 @@ def _limb_dot_mod(a, b, contract_a: int, contract_b: int):
     return field.recombine_limb_groups(groups)
 
 
-def _fused_block(x, w, c_ref, o_ref, pre: tuple, *, degree: int, dc: int):
-    """Shared body: one (bm, d) row block of one client's coded slice.
+def chunks(d: int, width: int):
+    """Static [start, stop) column chunks of width <= `width` covering d
+    (the last one ragged when width does not divide d)."""
+    return [(s, min(s + width, d)) for s in range(0, d, width)]
 
-    `pre` indexes into o_ref ahead of the d-slice: () for the single-client
-    kernel's (d,) output block, (0,) for the batched kernel's (1, d) block.
-    """
-    bm, d = x.shape
 
-    # pass 1: z = (X_blk @ w) mod p, chunked over d for f32 exactness
-    z = jnp.zeros((bm,), jnp.int32)
-    for c in range(0, d, dc):
-        xc = x[:, c:c + dc]
-        wc = w[c:c + dc]
-        z = field.add(z, _limb_dot_mod(xc, wc[:, None], 1, 0)[:, 0])
+def accumulate_rows(x_ref, w_ref, c_ref, f_ref, *, degree: int, dc: int):
+    """f[0] += ghat(W X^T) X for one (bm, d) row block of one client.
 
-    # ghat(z): unrolled Horner (VPU)
+    x_ref: (1, bm, d) coded rows; w_ref: (1, C, d) class-major coded model;
+    c_ref: (r+1,) SMEM coefficients; f_ref: (1, C, d) accumulator.  Every
+    contraction is <= 1024 wide (dc for pass 1, bm for pass 2), keeping the
+    f32 limb products exact; d may be ragged w.r.t. dc."""
+    spans = chunks(x_ref.shape[2], dc)
+    z = None
+    for s, e in spans:
+        part = _limb_dot_mod(w_ref[0, :, s:e], x_ref[0, :, s:e], 1, 1)
+        z = part if z is None else field.add(z, part)         # (C, bm)
     g = jnp.broadcast_to(c_ref[degree], z.shape)
     for t in range(degree - 1, -1, -1):
         g = field.add(field.mul(g, z), jnp.broadcast_to(c_ref[t], z.shape))
-
-    # pass 2: acc += X_blk^T g  (contraction over bm <= 1024)
-    for c in range(0, d, dc):
-        xc = x[:, c:c + dc]
-        upd = _limb_dot_mod(xc, g[:, None], 0, 0)[:, 0]   # (dc,)
-        sl = pre + (slice(c, c + dc),)
-        o_ref[sl] = field.add(o_ref[sl], upd)
+    for s, e in spans:
+        upd = _limb_dot_mod(g, x_ref[0, :, s:e], 1, 0)         # (C, e - s)
+        f_ref[0, :, s:e] = field.add(f_ref[0, :, s:e], upd)
 
 
-def _kernel(x_ref, w_ref, c_ref, o_ref, *, degree: int, dc: int):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+def _kernel(x_ref, w_ref, c_ref, f_ref, *, degree: int, dc: int):
+    @pl.when(pl.program_id(1) == 0)     # first row block of this client
     def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+        f_ref[...] = jnp.zeros_like(f_ref)
 
-    _fused_block(x_ref[...], w_ref[...], c_ref, o_ref, (),
-                 degree=degree, dc=dc)
-
-
-def _kernel_batched(x_ref, w_ref, c_ref, o_ref, *, degree: int, dc: int):
-    i = pl.program_id(1)                # row-block index (innermost)
-
-    @pl.when(i == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    _fused_block(x_ref[0], w_ref[0], c_ref, o_ref, (0,),
-                 degree=degree, dc=dc)
+    accumulate_rows(x_ref, w_ref, c_ref, f_ref, degree=degree, dc=dc)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("bm", "dc", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bm", "dc", "interpret"))
 def coded_gradient(x, w, coeffs, *, bm: int = DEFAULT_BM,
-                   dc: int = DEFAULT_DC, interpret: bool = True):
-    """f = (x^T ghat(x @ w)) mod p.
+                   dc: int = DEFAULT_DC, interpret: bool = False):
+    """f[n] = (ghat(w[n] x[n]^T) x[n]) mod p for all N clients at once.
 
-    x: (m, d) int32 field; w: (d,); coeffs: (r+1,).  m % bm == 0,
-    d % dc == 0 (ops.py pads); bm, dc <= 1024.
+    x: (N, m, d) int32 field; w: (N, C, d) class-major coded models;
+    coeffs: (r+1,) shared across clients and classes.  Returns (N, C, d).
+    m % bm == 0 (ops.py pads); bm, dc <= 1024.  Grid (N, m/bm): the
+    row-block dimension is innermost so client n's output block and w~_n
+    stay VMEM-resident across its whole slice.
     """
-    m, d = x.shape
-    assert m % bm == 0 and d % dc == 0, (x.shape, bm, dc)
-    assert bm <= 1024 and dc <= 1024
-    degree = coeffs.shape[0] - 1
-    return pl.pallas_call(
-        functools.partial(_kernel, degree=degree, dc=dc),
-        grid=(m // bm,),
-        in_specs=[
-            pl.BlockSpec((bm, d), lambda i: (i, 0)),
-            pl.BlockSpec((d,), lambda i: (0,)),
-            pl.BlockSpec((coeffs.shape[0],), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((d,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((d,), jnp.int32),
-        interpret=interpret,
-    )(x, w, coeffs)
-
-
-def _fused_block_matrix(x, w, c_ref, o_ref, *, degree: int, dc: int):
-    """One (bm, d) row block of one client's coded slice against a (d, C)
-    matrix model: the class-batched twin of _fused_block.  Both passes are
-    (.., dc) x (dc, C)-ish GEMMs with C in the free dimension; the
-    contraction widths (dc for pass 1, bm for pass 2) keep the f32 limb
-    products exact as in the vector kernel."""
-    bm, d = x.shape
+    nb, m, d = x.shape
     c = w.shape[1]
-
-    # pass 1: Z = (X_blk @ W) mod p, chunked over d for f32 exactness
-    z = jnp.zeros((bm, c), jnp.int32)
-    for s in range(0, d, dc):
-        z = field.add(z, _limb_dot_mod(x[:, s:s + dc], w[s:s + dc, :], 1, 0))
-
-    # ghat(Z): unrolled Horner (VPU), elementwise over the (bm, C) block
-    g = jnp.broadcast_to(c_ref[degree], z.shape)
-    for t in range(degree - 1, -1, -1):
-        g = field.add(field.mul(g, z), jnp.broadcast_to(c_ref[t], z.shape))
-
-    # pass 2: acc += X_blk^T G  (contraction over bm <= 1024)
-    for s in range(0, d, dc):
-        upd = _limb_dot_mod(x[:, s:s + dc], g, 0, 0)          # (dc, C)
-        o_ref[0, s:s + dc, :] = field.add(o_ref[0, s:s + dc, :], upd)
-
-
-def _kernel_matrix(x_ref, w_ref, c_ref, o_ref, *, degree: int, dc: int):
-    i = pl.program_id(1)                # row-block index (innermost)
-
-    @pl.when(i == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    _fused_block_matrix(x_ref[0], w_ref[0], c_ref, o_ref,
-                        degree=degree, dc=dc)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("bm", "dc", "interpret"))
-def coded_gradient_matrix(x, w, coeffs, *, bm: int = DEFAULT_BM,
-                          dc: int = DEFAULT_DC, interpret: bool = True):
-    """f[n] = (x[n]^T ghat(x[n] @ w[n])) mod p for (N, d, C) matrix models.
-
-    x: (N, m, d) int32 field; w: (N, d, C); coeffs: (r+1,) shared across
-    clients and classes.  m % bm == 0, d % dc == 0 (ops.py pads); the class
-    width C rides in the GEMM free dimension (C <= 1024 to keep the output
-    block VMEM-resident).  Grid (N, m/bm), row blocks innermost, exactly as
-    the vector kernel.
-    """
-    nb, m, d = x.shape
-    assert w.shape[:2] == (nb, d), (x.shape, w.shape)
-    c = w.shape[2]
-    assert m % bm == 0 and d % dc == 0, (x.shape, bm, dc)
-    assert bm <= 1024 and dc <= 1024 and c <= 1024
-    degree = coeffs.shape[0] - 1
-    return pl.pallas_call(
-        functools.partial(_kernel_matrix, degree=degree, dc=dc),
-        grid=(nb, m // bm),
-        in_specs=[
-            pl.BlockSpec((1, bm, d), lambda n, i: (n, i, 0)),
-            pl.BlockSpec((1, d, c), lambda n, i: (n, 0, 0)),
-            pl.BlockSpec((coeffs.shape[0],), lambda n, i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, d, c), lambda n, i: (n, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, d, c), jnp.int32),
-        interpret=interpret,
-    )(x, w, coeffs)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("bm", "dc", "interpret"))
-def coded_gradient_batched(x, w, coeffs, *, bm: int = DEFAULT_BM,
-                           dc: int = DEFAULT_DC, interpret: bool = True):
-    """f[n] = (x[n]^T ghat(x[n] @ w[n])) mod p for all N clients at once.
-
-    x: (N, m, d) int32 field; w: (N, d); coeffs: (r+1,) shared across
-    clients (same ghat everywhere).  m % bm == 0, d % dc == 0 (ops.py pads).
-    Grid (N, m/bm): the row-block dimension is innermost so client n's
-    output block and w~_n stay VMEM-resident across its whole slice.
-    """
-    nb, m, d = x.shape
-    assert w.shape == (nb, d), (x.shape, w.shape)
-    assert m % bm == 0 and d % dc == 0, (x.shape, bm, dc)
+    assert w.shape == (nb, c, d), (x.shape, w.shape)
+    assert m % bm == 0, (x.shape, bm)
     assert bm <= 1024 and dc <= 1024
-    degree = coeffs.shape[0] - 1
     return pl.pallas_call(
-        functools.partial(_kernel_batched, degree=degree, dc=dc),
+        functools.partial(_kernel, degree=coeffs.shape[0] - 1, dc=dc),
         grid=(nb, m // bm),
         in_specs=[
             pl.BlockSpec((1, bm, d), lambda n, i: (n, i, 0)),
-            pl.BlockSpec((1, d), lambda n, i: (n, 0)),
-            pl.BlockSpec((coeffs.shape[0],), lambda n, i: (0,)),
+            pl.BlockSpec((1, c, d), lambda n, i: (n, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((1, d), lambda n, i: (n, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, d), jnp.int32),
+        out_specs=pl.BlockSpec((1, c, d), lambda n, i: (n, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb, c, d), jnp.int32),
         interpret=interpret,
     )(x, w, coeffs)

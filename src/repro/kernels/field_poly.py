@@ -1,7 +1,7 @@
 """Pallas TPU kernel: elementwise Horner evaluation of ghat over F_p.
 
 VPU-bound elementwise kernel; the coefficient vector (r+1 elements, r <= 3 in
-the paper) rides along in SMEM-sized VMEM and the Horner chain is unrolled
+the paper) is read as scalars from SMEM and the Horner chain is unrolled
 statically.  All int32 (13-bit-limb modular multiplies).
 """
 
@@ -12,6 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core import field
 
@@ -27,7 +28,7 @@ def _kernel(z_ref, c_ref, o_ref, *, degree: int):
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def poly_eval(z, coeffs, *, block: int = DEFAULT_BLOCK, interpret: bool = True):
+def poly_eval(z, coeffs, *, block: int = DEFAULT_BLOCK, interpret: bool = False):
     """Evaluate sum_i coeffs[i] z^i over F_p elementwise.
 
     z: (L,) int32 field elements, L % block == 0 (ops.py pads);
@@ -41,7 +42,7 @@ def poly_eval(z, coeffs, *, block: int = DEFAULT_BLOCK, interpret: bool = True):
         grid=(l // block,),
         in_specs=[
             pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((coeffs.shape[0],), lambda i: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((l,), jnp.int32),

@@ -4,8 +4,8 @@ The phase-siloed hot loop costs four dispatches per iteration -- gradient
 GEMM pair (kernels/coded_gradient.py), decode matvec, q_eta scale, TruncPr
 share arithmetic -- each with its own HBM round-trip over the (N, dw) share
 state.  This kernel runs the whole post-encode step on the (N, m/bm) grid of
-the batched gradient kernel and finishes the protocol arithmetic in the
-kernel epilogue, so one `pallas_call` per iteration touches X~ exactly once:
+the gradient kernel and finishes the protocol arithmetic in the kernel
+epilogue, so one `pallas_call` per iteration touches X~ exactly once:
 
   per row block (the double-buffered pipeline body):
       z = X~_blk @ W~          (limb GEMM, dc-chunked contraction)
@@ -45,14 +45,18 @@ property/golden tests and documented in docs/ARCHITECTURE.md:
 
 Every quantity is a canonical representative in [0, p), so any exact mod-p
 evaluation order produces bit-identical int32 -- the pinned sha256 goldens
-in tests/test_api.py hold with this kernel active.
+in tests/goldens.py hold with this kernel active.
 
-Shapes are the class-batched MATRIX form (C = 1 recovers the vector path:
-the limb GEMMs are literally the same dot_general calls).  d need not be a
-multiple of dc (the chunk loop takes a ragged tail); m is padded to bm with
-zero rows by ops.py (zero rows contribute zero to X~^T g).  VMEM budget:
-the six (N, d, C) epilogue operands stay resident, so N * d * C should be
-kept well under the ~16 MB/core budget (true for every paper scale).
+Layout (what Mosaic accepts): models and gradients are class-major,
+(N, C, d), and the five epilogue planes are (C, N, d), so d always rides
+the 128-lane axis and the per-class epilogue works on lane-dense (N, d)
+slabs, tiled over d in dc-wide chunks.  Per-client scalars (adv_off,
+dfull) and the ghat coefficients live in SMEM; the open row rvec is read
+as a (1, N) vector and stays in VMEM.  d need not be a multiple of dc (the
+chunk loops take a ragged tail); m is padded to bm with zero rows by ops.py
+(zero rows contribute zero to X~^T g).  VMEM budget: the six epilogue
+planes stay resident, about 6 * C * N * d * 4 bytes after (8, 128) tile
+padding -- 4.3 MB at the paper's N=50, d=3073, C=1.
 """
 
 from __future__ import annotations
@@ -62,37 +66,20 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core import field
-from .coded_gradient import DEFAULT_BM, DEFAULT_DC, _limb_dot_mod
-
-
-def _gradient_block(x, w, c_ref, f_ref, *, degree: int, dc: int):
-    """One (bm, d) row block of one client: f += X_blk^T ghat(X_blk @ W).
-
-    Same math as coded_gradient._fused_block_matrix but tolerant of a
-    ragged final d-chunk (static slicing clamps; every chunk <= dc <= 1024
-    keeps the f32 limb accumulation exact)."""
-    d = x.shape[1]
-    z = jnp.zeros((x.shape[0], w.shape[1]), jnp.int32)
-    for s in range(0, d, dc):
-        z = field.add(z, _limb_dot_mod(x[:, s:s + dc], w[s:s + dc, :], 1, 0))
-    g = jnp.broadcast_to(c_ref[degree], z.shape)
-    for t in range(degree - 1, -1, -1):
-        g = field.add(field.mul(g, z), jnp.broadcast_to(c_ref[t], z.shape))
-    for s in range(0, d, dc):
-        upd = _limb_dot_mod(x[:, s:s + dc], g, 0, 0)
-        f_ref[0, s:s + dc, :] = field.add(f_ref[0, s:s + dc, :], upd)
+from .coded_gradient import (DEFAULT_BM, DEFAULT_DC, _limb_dot_mod,
+                             accumulate_rows, chunks)
 
 
 def _kernel(x_ref, w_ref, c_ref, adv_ref, dfull_ref, rvec_ref, base_ref,
-            xty_ref, wsh_ref, radd_ref, r0sh_ref, f_ref, common_ref,
-            wout_ref, *, degree: int, dc: int, q_eta: int, inv2k1: int,
+            xty_ref, wsh_ref, radd_ref, r0sh_ref, f_ref, wout_ref,
+            common_ref, *, degree: int, dc: int, q_eta: int, inv2k1: int,
             k1: int):
     n = pl.program_id(0)                # client (outer)
     i = pl.program_id(1)                # row block (innermost)
-    ncl = pl.num_programs(0)
-    nblk = pl.num_programs(1)
+    last_blk = i == pl.num_programs(1) - 1
 
     @pl.when(jnp.logical_and(n == 0, i == 0))
     def _init_common():
@@ -102,34 +89,33 @@ def _kernel(x_ref, w_ref, c_ref, adv_ref, dfull_ref, rvec_ref, base_ref,
     def _init_f():
         f_ref[...] = jnp.zeros_like(f_ref)
 
-    _gradient_block(x_ref[0], w_ref[0], c_ref, f_ref, degree=degree, dc=dc)
+    accumulate_rows(x_ref, w_ref, c_ref, f_ref, degree=degree, dc=dc)
 
-    @pl.when(i == nblk - 1)
+    @pl.when(last_blk)
     def _fold_client():
         # client n's gradient is complete: inject the (possibly zero)
         # corruption offset and fold into the decode accumulator with this
         # client's public decode weight (zero = excluded from the subset)
-        f_adj = field.add(f_ref[0], jnp.full((), adv_ref[n], jnp.int32))
-        contrib = field.mul(f_adj, jnp.full((), dfull_ref[n], jnp.int32))
-        common_ref[...] = field.add(common_ref[...], contrib)
+        f_adj = field.add(f_ref[0], adv_ref[n])
+        common_ref[...] = field.add(common_ref[...],
+                                    field.mul(f_adj, dfull_ref[n]))
 
-    @pl.when(jnp.logical_and(n == ncl - 1, i == nblk - 1))
+    @pl.when(jnp.logical_and(n == pl.num_programs(0) - 1, last_blk))
     def _epilogue():
-        # Phase 4 on shares, entirely in VMEM: decode + update + TruncPr
-        xtg = field.add(base_ref[...], common_ref[...][None])
-        grad = field.sub(xtg, xty_ref[...])
-        scaled = field.mul_scalar(grad, q_eta)
-        c_sh = field.add(scaled, radd_ref[...])
-        nc = c_sh.shape[0]
-        # masked OPEN: Lagrange row over holders (contraction N <= 1024)
-        c_open = _limb_dot_mod(rvec_ref[...][None, :],
-                               c_sh.reshape(nc, -1), 1, 0)[0]
-        c_open = c_open.reshape(c_sh.shape[1:])
-        c0 = jnp.bitwise_and(c_open, (1 << k1) - 1)
-        a0 = field.sub(jnp.broadcast_to(c0[None], c_sh.shape),
-                       r0sh_ref[...])
-        delta = field.mul_scalar(field.sub(scaled, a0), inv2k1)
-        wout_ref[...] = field.sub(wsh_ref[...], delta)
+        # Phase 4 on shares, entirely in VMEM: decode + update + TruncPr,
+        # one lane-dense (N, e - s) slab of one class at a time
+        for c in range(common_ref.shape[0]):
+            for s, e in chunks(common_ref.shape[1], dc):
+                xtg = field.add(base_ref[c, :, s:e], common_ref[c:c + 1, s:e])
+                scaled = field.mul_scalar(
+                    field.sub(xtg, xty_ref[c, :, s:e]), q_eta)
+                c_sh = field.add(scaled, radd_ref[c, :, s:e])
+                # masked OPEN: Lagrange row over holders (contraction N)
+                c_open = _limb_dot_mod(rvec_ref[...], c_sh, 1, 0)  # (1, e-s)
+                c0 = jnp.bitwise_and(c_open, (1 << k1) - 1)
+                a0 = field.sub(c0, r0sh_ref[c, :, s:e])
+                delta = field.mul_scalar(field.sub(scaled, a0), inv2k1)
+                wout_ref[c, :, s:e] = field.sub(wsh_ref[c, :, s:e], delta)
 
 
 @functools.partial(
@@ -138,46 +124,47 @@ def _kernel(x_ref, w_ref, c_ref, adv_ref, dfull_ref, rvec_ref, base_ref,
 def fused_step(x, w, coeffs, adv_off, dfull, rvec, base, xty, wsh, radd,
                r0sh, *, q_eta: int, inv2k1: int, k1: int,
                bm: int = DEFAULT_BM, dc: int = DEFAULT_DC,
-               interpret: bool = True):
+               interpret: bool = False):
     """One COPML GD step (post model-encode) as a single pallas_call.
 
-    x: (N, m, d) coded slices; w: (N, d, C) coded models; coeffs: (r+1,).
-    adv_off/dfull/rvec: (N,) per-client corruption offsets, decode row,
-    open row.  base/xty/wsh/radd/r0sh: (N, d, C) epilogue operands (see
-    module docstring).  Returns (f, new_w): the per-client coded gradients
-    (pre-corruption, matching coded_gradient_matrix) and the updated model
-    shares.  m % bm == 0 (ops.py pads); N <= 1024 bounds the open
-    contraction; d may be ragged w.r.t. dc.
+    x: (N, m, d) coded slices; w: (N, C, d) class-major coded models;
+    coeffs: (r+1,).  adv_off/dfull: (N,) per-client corruption offsets and
+    decode row; rvec: (1, N) open row.  base/xty/wsh/radd/r0sh: (C, N, d)
+    epilogue planes (see module docstring).  Returns (f, new_w): the
+    per-client coded gradients (N, C, d) (pre-corruption, matching the
+    gradient kernel) and the updated model shares (C, N, d).  m % bm == 0
+    (ops.py pads); N <= 1024 bounds the open contraction; d may be ragged
+    w.r.t. dc.
     """
     nb, m, d = x.shape
-    c = w.shape[2]
-    assert w.shape == (nb, d, c), (x.shape, w.shape)
+    c = w.shape[1]
+    assert w.shape == (nb, c, d), (x.shape, w.shape)
     assert m % bm == 0, (x.shape, bm)
     assert bm <= 1024 and dc <= 1024 and nb <= 1024
+    assert rvec.shape == (1, nb), rvec.shape
     for arr in (base, xty, wsh, radd, r0sh):
-        assert arr.shape == (nb, d, c), (arr.shape, (nb, d, c))
-    degree = coeffs.shape[0] - 1
-    nvec = pl.BlockSpec((nb,), lambda n, i: (0,))
-    full = pl.BlockSpec((nb, d, c), lambda n, i: (0, 0, 0))
+        assert arr.shape == (c, nb, d), (arr.shape, (c, nb, d))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    whole = pl.BlockSpec((c, nb, d), lambda n, i: (0, 0, 0))
     return pl.pallas_call(
-        functools.partial(_kernel, degree=degree, dc=dc, q_eta=q_eta,
-                          inv2k1=inv2k1, k1=k1),
+        functools.partial(_kernel, degree=coeffs.shape[0] - 1, dc=dc,
+                          q_eta=q_eta, inv2k1=inv2k1, k1=k1),
         grid=(nb, m // bm),
         in_specs=[
             pl.BlockSpec((1, bm, d), lambda n, i: (n, i, 0)),
-            pl.BlockSpec((1, d, c), lambda n, i: (n, 0, 0)),
-            pl.BlockSpec((coeffs.shape[0],), lambda n, i: (0,)),
-            nvec, nvec, nvec, full, full, full, full, full,
+            pl.BlockSpec((1, c, d), lambda n, i: (n, 0, 0)),
+            smem, smem, smem,
+            pl.BlockSpec((1, nb), lambda n, i: (0, 0)),
+            whole, whole, whole, whole, whole,
         ],
         out_specs=[
-            pl.BlockSpec((1, d, c), lambda n, i: (n, 0, 0)),
-            pl.BlockSpec((d, c), lambda n, i: (0, 0)),
-            full,
+            pl.BlockSpec((1, c, d), lambda n, i: (n, 0, 0)),
+            whole,
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nb, d, c), jnp.int32),    # f
-            jax.ShapeDtypeStruct((d, c), jnp.int32),        # common
-            jax.ShapeDtypeStruct((nb, d, c), jnp.int32),    # new_w
+            jax.ShapeDtypeStruct((nb, c, d), jnp.int32),    # f
+            jax.ShapeDtypeStruct((c, nb, d), jnp.int32),    # new_w
         ],
+        scratch_shapes=[pltpu.VMEM((c, d), jnp.int32)],     # common
         interpret=interpret,
-    )(x, w, coeffs, adv_off, dfull, rvec, base, xty, wsh, radd, r0sh)[::2]
+    )(x, w, coeffs, adv_off, dfull, rvec, base, xty, wsh, radd, r0sh)

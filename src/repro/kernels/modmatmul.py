@@ -68,7 +68,7 @@ def _kernel(a_ref, b_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def modmatmul(a, b, *, bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
-              bk: int = DEFAULT_BK, interpret: bool = True):
+              bk: int = DEFAULT_BK, interpret: bool = False):
     """(a @ b) mod p.  a: (M, K), b: (K, N) int32 field elements.
 
     Shapes must be multiples of the block sizes (ops.py pads).
@@ -104,7 +104,7 @@ def _kernel_batched(a_ref, b_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def modmatmul_batched(a, b, *, bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
-                      bk: int = DEFAULT_BK, interpret: bool = True):
+                      bk: int = DEFAULT_BK, interpret: bool = False):
     """(a[i] @ b[i]) mod p for all i.  a: (B, M, K), b: (B, K, N) int32.
 
     M/N/K must be multiples of the block sizes (ops.py pads).

@@ -1,8 +1,11 @@
-"""Public jit'd wrappers for the Pallas kernels: padding, dispatch, fallback.
+"""Public wrappers for the Pallas kernels: padding, layout and dispatch.
 
-On this CPU container the kernels run in interpret mode (the kernel body
-executes exactly as written); on TPU set REPRO_PALLAS_INTERPRET=0.  Small
-shapes fall back to the pure-jnp reference (padding overhead would dominate).
+Each entry point runs the pure-jnp reference (kernels/ref.py) unless
+REPRO_USE_PALLAS=1 or the caller forces the kernel.  A kernel runs in
+interpret mode exactly when the default backend is the CPU (the body
+executes as written, so the tests verify its logic there) and is compiled
+by Mosaic everywhere else; a kernel that Mosaic refuses raises -- nothing
+falls back to the reference behind the caller's back.
 """
 
 from __future__ import annotations
@@ -10,6 +13,7 @@ from __future__ import annotations
 import json
 import os
 
+import jax
 import jax.numpy as jnp
 
 from . import coded_gradient as _cg
@@ -19,9 +23,17 @@ from . import modmatmul as _mm
 from . import ref
 from ..core.labels import Coded, Public
 
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 # interpret-mode kernels are slow on CPU; route big shapes only when asked
 USE_PALLAS = os.environ.get("REPRO_USE_PALLAS", "0") != "0"
+
+
+def interpret_mode() -> bool:
+    """Interpret the Pallas kernels iff the default backend is the CPU.
+
+    Asked per call and never at import: querying the backend initialises
+    it, and on a TPU host that claims the chip for this process."""
+    return jax.default_backend() == "cpu"
+
 
 # ---------------------------------------------------------------------------
 # (bm, dc) block selection for the gradient-family kernels.
@@ -67,7 +79,10 @@ def pick_blocks(m: int, d: int, c: int = 1) -> tuple[int, int]:
     width: the matrix path's VMEM block holds (bm, d) of X~ plus the
     (dc, C) output slice, so dc is shrunk when C is wide instead of
     reusing the vector-path minimum (which padded ragged class-batched
-    shapes pathologically -- see the (m=13, C=10) regression test).
+    shapes pathologically -- see the (m=13, C=10) regression test).  bm
+    shrinks for wide d: the double-buffered (bm, d) X~ block is capped at
+    2^20 elements (4 MiB a buffer), which with the limb temporaries keeps
+    the kernel inside Mosaic's 16 MiB scoped VMEM (d = 5000 needs bm 128).
     """
     env = os.environ.get("REPRO_PALLAS_BLOCKS", "")
     if env:
@@ -77,6 +92,8 @@ def pick_blocks(m: int, d: int, c: int = 1) -> tuple[int, int]:
     if entry:
         return int(entry["bm"]), int(entry["dc"])
     bm = min(_cg.DEFAULT_BM, _bucket(m))
+    while bm > 8 and bm * _bucket(d) > (1 << 20):
+        bm //= 2
     dc = min(_cg.DEFAULT_DC, _bucket(d))
     while c > 1 and dc * _bucket(c) > 16384 and dc > 8:
         dc //= 2
@@ -105,7 +122,8 @@ def modmatmul(a, b, *, bm=None, bn=None, bk=None, force_pallas: bool = False):
     a, _ = _pad_to(a, 1, bk)
     b, _ = _pad_to(b, 0, bk)
     b, _ = _pad_to(b, 1, bn)
-    out = _mm.modmatmul(a, b, bm=bm, bn=bn, bk=bk, interpret=INTERPRET)
+    out = _mm.modmatmul(a, b, bm=bm, bn=bn, bk=bk,
+                        interpret=interpret_mode())
     return out[:m, :n]
 
 
@@ -130,7 +148,7 @@ def modmatmul_batched(a, b, *, bm=None, bn=None, bk=None,
     b, _ = _pad_to(b, 1, bk)
     b, _ = _pad_to(b, 2, bn)
     out = _mm.modmatmul_batched(a, b, bm=bm, bn=bn, bk=bk,
-                                interpret=INTERPRET)
+                                interpret=interpret_mode())
     return out[:, :m, :n]
 
 
@@ -142,25 +160,34 @@ def poly_eval(z, coeffs, *, block=None, force_pallas: bool = False):
     flat = z.reshape(-1)
     block = block or min(_fp.DEFAULT_BLOCK, max(8, flat.shape[0]))
     flat, pad = _pad_to(flat, 0, block)
-    out = _fp.poly_eval(flat, coeffs, block=block, interpret=INTERPRET)
+    out = _fp.poly_eval(flat, coeffs, block=block,
+                        interpret=interpret_mode())
     if pad:
         out = out[:-pad]
     return out.reshape(shape)
 
 
+def _gradient_rows(x, w_rows, coeffs, bm, dc):
+    """The gradient kernel on class-major models w_rows: (N, C, d), with
+    m padded to bm and d to dc (zero rows/columns contribute nothing)."""
+    d0 = x.shape[2]
+    tbm, tdc = pick_blocks(x.shape[1], d0, w_rows.shape[1])
+    bm = bm or tbm
+    dc = dc or tdc
+    x, _ = _pad_to(x, 1, bm)
+    x, _ = _pad_to(x, 2, dc)
+    w_rows, _ = _pad_to(w_rows, 2, dc)
+    out = _cg.coded_gradient(x, w_rows, coeffs, bm=bm, dc=dc,
+                             interpret=interpret_mode())
+    return out[:, :, :d0]
+
+
 def coded_gradient(x: Coded, w: Coded, coeffs: Public, *, bm=None, dc=None,
                    force_pallas: bool = False) -> Coded:
-    """Fused f = x^T ghat(x w) over F_p (COPML Eq. 7)."""
+    """Fused f = x^T ghat(x w) over F_p (COPML Eq. 7) for one client."""
     if not (USE_PALLAS or force_pallas):
         return ref.coded_gradient(x, w, coeffs)
-    d0 = x.shape[1]
-    bm = bm or min(_cg.DEFAULT_BM, max(8, x.shape[0]))
-    dc = dc or min(_cg.DEFAULT_DC, max(8, d0))
-    x, _ = _pad_to(x, 0, bm)
-    x, dpad = _pad_to(x, 1, dc)
-    w, _ = _pad_to(w, 0, dc)
-    out = _cg.coded_gradient(x, w, coeffs, bm=bm, dc=dc, interpret=INTERPRET)
-    return out[:d0] if dpad else out
+    return _gradient_rows(x[None], w[None, None], coeffs, bm, dc)[0, 0]
 
 
 def coded_gradient_batched(x: Coded, w: Coded, coeffs: Public, *, bm=None,
@@ -172,16 +199,7 @@ def coded_gradient_batched(x: Coded, w: Coded, coeffs: Public, *, bm=None,
     """
     if not (USE_PALLAS or force_pallas):
         return ref.coded_gradient_batched(x, w, coeffs)
-    d0 = x.shape[2]
-    tbm, tdc = pick_blocks(x.shape[1], d0)
-    bm = bm or tbm
-    dc = dc or tdc
-    x, _ = _pad_to(x, 1, bm)
-    x, dpad = _pad_to(x, 2, dc)
-    w, _ = _pad_to(w, 1, dc)
-    out = _cg.coded_gradient_batched(x, w, coeffs, bm=bm, dc=dc,
-                                     interpret=INTERPRET)
-    return out[:, :d0] if dpad else out
+    return _gradient_rows(x, w[:, None], coeffs, bm, dc)[:, 0]
 
 
 def coded_gradient_matrix(x: Coded, w: Coded, coeffs: Public, *, bm=None,
@@ -194,16 +212,8 @@ def coded_gradient_matrix(x: Coded, w: Coded, coeffs: Public, *, bm=None,
     """
     if not (USE_PALLAS or force_pallas):
         return ref.coded_gradient_matrix(x, w, coeffs)
-    d0 = x.shape[2]
-    tbm, tdc = pick_blocks(x.shape[1], d0, w.shape[2])
-    bm = bm or tbm
-    dc = dc or tdc
-    x, _ = _pad_to(x, 1, bm)
-    x, dpad = _pad_to(x, 2, dc)
-    w, _ = _pad_to(w, 1, dc)
-    out = _cg.coded_gradient_matrix(x, w, coeffs, bm=bm, dc=dc,
-                                    interpret=INTERPRET)
-    return out[:, :d0] if dpad else out
+    f = _gradient_rows(x, jnp.swapaxes(w, 1, 2), coeffs, bm, dc)
+    return jnp.swapaxes(f, 1, 2)
 
 
 def fused_step(x, w, coeffs, adv_off, dfull, rvec, base, xty, wsh, radd,
@@ -211,10 +221,12 @@ def fused_step(x, w, coeffs, adv_off, dfull, rvec, base, xty, wsh, radd,
                force_pallas: bool = False):
     """Full COPML Phase-3/4 step (post model-encode) as ONE dispatch.
 
-    See kernels/fused_step.py for the operand contract.  Pads only the
-    sample axis m (zero rows are exact: they contribute nothing to X~^T g);
-    the kernel takes d ragged.  Falls back to the phase-by-phase reference
-    composition when Pallas is not requested.
+    Operands and returns in the reference layout (ref.fused_step): w and
+    the epilogue planes (N, d, C), adv_off/dfull/rvec (N,).  Moves them to
+    the kernel's class-major layout (kernels/fused_step.py; free reshapes
+    for C = 1) and pads only the sample axis m (zero rows are exact: they
+    contribute nothing to X~^T g); the kernel takes d ragged.  Runs the
+    phase-by-phase reference composition when Pallas is not requested.
     """
     if not (USE_PALLAS or force_pallas):
         return ref.fused_step(x, w, coeffs, adv_off, dfull, rvec, base, xty,
@@ -224,6 +236,13 @@ def fused_step(x, w, coeffs, adv_off, dfull, rvec, base, xty, wsh, radd,
     bm = bm or tbm
     dc = dc or min(tdc, _bucket(x.shape[2]))
     x, _ = _pad_to(x, 1, bm)
-    return _fs.fused_step(x, w, coeffs, adv_off, dfull, rvec, base, xty,
-                          wsh, radd, r0sh, q_eta=q_eta, inv2k1=inv2k1,
-                          k1=k1, bm=bm, dc=dc, interpret=INTERPRET)
+
+    def plane(a):                       # (N, d, C) -> (C, N, d)
+        return jnp.transpose(a, (2, 0, 1))
+
+    f, new_w = _fs.fused_step(
+        x, jnp.swapaxes(w, 1, 2), coeffs, adv_off, dfull, rvec[None],
+        plane(base), plane(xty), plane(wsh), plane(radd), plane(r0sh),
+        q_eta=q_eta, inv2k1=inv2k1, k1=k1, bm=bm, dc=dc,
+        interpret=interpret_mode())
+    return jnp.swapaxes(f, 1, 2), jnp.transpose(new_w, (1, 2, 0))

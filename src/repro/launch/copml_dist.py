@@ -231,6 +231,8 @@ def main(argv=None) -> None:
     ap.add_argument("--bench", action="store_true",
                     help="print benchmark CSV rows instead of the parity demo")
     args = ap.parse_args(argv)
+    from ..api import compile_cache
+    compile_cache.enable()
     if args.devices is None:
         args.devices = len(jax.devices())
     if len(jax.devices()) < 2:

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 
 from ..configs import registry
-from ..core import meshutil
 from ..models import model_zoo as MZ
 from ..models.config import applicable_shapes, ALL_SHAPES
 from ..sharding import partition
@@ -87,7 +86,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir=None,
                     "status": "skipped (full attention at 500k context, "
                               "DESIGN.md section 6)"}
         fn, args = _step_fn_and_args(cfg, shape, mesh, **tuning)
-        with meshutil.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn).lower(*args)
             compiled = lowered.compile()
         mem = compiled.memory_analysis()

@@ -51,6 +51,17 @@ def run_copml_proc(proto, key, client_xs, client_ys, iters: int, *,
     P = min(P, n)
     if P < 1:
         raise ValueError(f"proc engine needs >= 1 process, got {P}")
+    platform = jax.default_backend()
+    if platform != "cpu":
+        # the coordinator's own setup holds the device, and a device belongs
+        # to one process: the workers' fresh JAX runtimes could not load it
+        # and would only time out at spawn_timeout_s
+        raise RuntimeError(
+            f"the proc engine cannot run on a {platform} host: this "
+            f"coordinator process holds the {platform} device, and each "
+            f"worker process would need a device of its own (no per-worker "
+            f"device assignment exists). Use the jit or sharded engine, or "
+            f"run proc:N with JAX_PLATFORMS=cpu.")
     ncfg = NetConfig.from_env() if net_cfg is None else net_cfg
     iters = int(iters)
     subset = None if subset is None else tuple(subset)

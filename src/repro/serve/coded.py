@@ -165,7 +165,6 @@ def sharded_scorer(model: CodedModel, mesh):
     logits are the only cross-shard product (all_gather + reconstruct,
     replicated).  Returns fn(queries float (B, d)) -> Opened field
     logits (B, C'), bit-identical to the single-device path."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     assert mesh.axis_names == (meshutil.CLIENT_AXIS,), mesh.axis_names
@@ -187,8 +186,8 @@ def sharded_scorer(model: CodedModel, mesh):
         return shamir.reconstruct(z_all, model.t, model.points)
 
     cl = P(meshutil.CLIENT_AXIS)
-    sm = shard_map(score, mesh, in_specs=(cl, P()), out_specs=P(),
-                   check_rep=False)
+    sm = jax.shard_map(score, mesh=mesh, in_specs=(cl, P()), out_specs=P(),
+                       check_vma=False)
 
     def fn(queries):
         xq = quantize_queries(model, queries)

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core import meshutil, secure_agg
+from ..core import secure_agg
 from ..data import pipeline
 from ..models import model_zoo as MZ
 from ..models.config import ModelConfig
@@ -79,7 +79,7 @@ def train(cfg: ModelConfig, tcfg: TrainConfig, mesh=None, callback=None):
 
     jit_step = jax.jit(step_fn, donate_argnums=(0, 1))
     history = []
-    ctx = meshutil.set_mesh(mesh) if mesh is not None else _nullcontext()
+    ctx = jax.set_mesh(mesh) if mesh is not None else _nullcontext()
     with ctx:
         for step in range(start_step, tcfg.steps):
             batch = pipeline.lm_batch(dcfg, step)

@@ -21,6 +21,15 @@ except ModuleNotFoundError:
     _mod.install()
 
 
+@pytest.fixture(autouse=True)
+def _no_persistent_compile_cache(monkeypatch):
+    """The entry points' main() turns on the persistent compilation cache
+    (repro.api.compile_cache); tests that call a main() leave the
+    process's JAX configuration alone."""
+    from repro.api import compile_cache
+    monkeypatch.setattr(compile_cache, "enable", lambda: None)
+
+
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(0)

@@ -1,9 +1,7 @@
 """repro.api facade: the (workload, protocol, engine) axes.
 
-The copml goldens below were produced by the PRE-refactor
-Copml.train_jit / train_sharded (commit e179bb5, before the api layer
-existed) on the smoke workload -- the facade must reproduce them
-bit-for-bit through every engine.
+The copml goldens (tests/goldens.py) pin the smoke workload's fit -- the
+facade must reproduce them bit-for-bit through every engine.
 """
 
 import hashlib
@@ -20,15 +18,9 @@ from repro.core import secure_agg as sa
 from repro.core.baselines import MpcBaseline
 from repro.core.protocol import Copml
 
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from goldens import GOLDEN_HIST_SHA, GOLDEN_SHARES_SHA, GOLDEN_W
 
-# smoke workload, key=PRNGKey(0), 10 iterations (pre-refactor outputs)
-GOLDEN_W = [0.25, -0.375, 0.375, 0.5, -0.125, 0.25, 0.875, 1.25, -0.5,
-            -1.125, -0.5, 0.125]
-GOLDEN_SHARES_SHA = \
-    "459aaa671b3d6708b4918f1e54b29e083cecf6c85b5b617f882720596399afaf"
-GOLDEN_HIST_SHA = \
-    "343e87b79c6ece3608774a43160dccbb80ef214111bdb0f9f9c066ead77f9e80"
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _sha(arr, dtype):
@@ -393,6 +385,42 @@ def test_multiclass_faultplan_bit_exact():
     churn_e = api.fit(wl, "copml", "eager", key=1, iters=3, history=True,
                       faults=plan)
     np.testing.assert_array_equal(churn_e.weights, churn.weights)
+
+
+def test_copml_driver_keyed_on_megakernel_gate(monkeypatch):
+    """REPRO_FUSED_STEP is read once per Copml, and api.fit caches one
+    Copml per (workload, gate): flipping the gate between fits builds a
+    new driver instead of silently reusing the old schedule."""
+    proto = api.PROTOCOLS["copml"]
+    wl = api.get_workload("smoke")
+    monkeypatch.setenv("REPRO_FUSED_STEP", "1")
+    fused = proto.driver(wl)
+    monkeypatch.setenv("REPRO_FUSED_STEP", "kernel")
+    kernel = proto.driver(wl)
+    assert kernel is not fused
+    assert (fused.fused_mode, kernel.fused_mode) == ("1", "kernel")
+    monkeypatch.setenv("REPRO_FUSED_STEP", "1")
+    assert proto.driver(wl) is fused
+
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/jax-cache"])
+def test_compile_cache_placement(monkeypatch, env_dir):
+    """The entry points' cache helper: JAX_COMPILATION_CACHE_DIR wins and
+    nothing else is set; otherwise the fixed <repo>/.jax_cache."""
+    monkeypatch.undo()              # the real helper, not conftest's stub
+    from repro.api import compile_cache
+    updates = []
+    monkeypatch.setattr(compile_cache.jax.config, "update",
+                        lambda name, value: updates.append((name, value)))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = os.path.join(_REPO, ".jax_cache")
+        assert compile_cache.enable() == want
+        assert updates == [("jax_compilation_cache_dir", want)]
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        assert compile_cache.enable() == env_dir
+        assert updates == []
 
 
 # ----------------------------------------------------------- cli + harness

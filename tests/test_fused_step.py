@@ -6,7 +6,7 @@ reference over random shapes/degrees/class widths; Barrett reduction and
 the grouped-limb matmul agree with plain `% P` arithmetic over the full
 reachable range.  Plus the tuned-block selection contract and the
 protocol-level golden: REPRO_FUSED_STEP=kernel (forced Pallas megakernel)
-reproduces the pre-refactor smoke-workload share hash bit-for-bit.
+reproduces the pinned smoke-workload share hash bit-for-bit.
 """
 
 import hashlib
@@ -18,12 +18,11 @@ from hypothesis import given, settings, strategies as st
 from repro.core import field as F
 from repro.kernels import ops, ref
 
+from goldens import GOLDEN_SHARES_SHA
+
 MAX_SEED = 2 ** 31 - 1
 K1 = 8
 
-# smoke workload golden (tests/test_api.py): key=PRNGKey(0), 10 iterations
-GOLDEN_SHARES_SHA = \
-    "459aaa671b3d6708b4918f1e54b29e083cecf6c85b5b617f882720596399afaf"
 
 
 def _operands(rng, n, m, d, c, degree):
@@ -124,7 +123,7 @@ def test_coded_gradient_matrix_ragged_regression():
 
 def test_forced_kernel_golden_shares(monkeypatch):
     """REPRO_FUSED_STEP=kernel (the Pallas megakernel inside the jit scan)
-    reproduces the pre-refactor smoke-workload share hash bit-for-bit."""
+    reproduces the pinned smoke-workload share hash bit-for-bit."""
     from repro import api
     monkeypatch.setenv("REPRO_FUSED_STEP", "kernel")
     res = api.fit("smoke", "copml", "jit", key=0, iters=10, history=False)

@@ -1,5 +1,9 @@
 """Per-kernel sweeps: Pallas (interpret=True) vs pure-jnp ref vs uint64."""
 
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -139,3 +143,18 @@ def test_block_shape_sweep(rng):
     for bm, dc in ((32, 32), (96, 160)):
         got = ops.coded_gradient(x, w, c, force_pallas=True, bm=bm, dc=dc)
         np.testing.assert_array_equal(np.asarray(got), expected)
+
+
+def test_import_leaves_backend_uninitialised():
+    """Interpret mode is chosen per call, never while a module is
+    imported: asking for the backend at import would claim the chip."""
+    code = ("import repro.api, repro.kernels.ops, repro.serve.coded\n"
+            "from jax._src import xla_bridge\n"
+            "print(len(xla_bridge._backends))")
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    env = dict(os.environ, PYTHONPATH=src)
+    out = subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                         capture_output=True, text=True, timeout=120)
+    assert out.stdout.strip() == "0"
+    assert ops.interpret_mode() == (jax.default_backend() == "cpu")

@@ -1,8 +1,7 @@
 """proc engine conformance: real OS processes + sockets, bit-exact COPML.
 
-The goldens are the SAME pre-refactor pins test_api.py holds for the jit
-engine (smoke, key=PRNGKey(0), 10 iterations) -- re-declared here so a
-drift in either file's constants is caught, not papered over.  The proc
+The goldens are the SAME pins (tests/goldens.py: smoke, key=PRNGKey(0),
+10 iterations) test_api.py holds the jit engine to.  The proc
 engine must reproduce them over real localhost TCP with measured (not
 modeled) communication, and a timeout-induced straggler run must decode
 from the surviving R-subset to the SAME bits (LCC decode invariance under
@@ -23,16 +22,9 @@ from repro import api
 from repro.analysis import choreography
 from repro.api import engine as engine_mod
 
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from goldens import GOLDEN_HIST_SHA, GOLDEN_SHARES_SHA, GOLDEN_W
 
-# smoke workload, key=PRNGKey(0), 10 iterations (pre-refactor outputs;
-# must stay equal to tests/test_api.py's copies)
-GOLDEN_W = [0.25, -0.375, 0.375, 0.5, -0.125, 0.25, 0.875, 1.25, -0.5,
-            -1.125, -0.5, 0.125]
-GOLDEN_SHARES_SHA = \
-    "459aaa671b3d6708b4918f1e54b29e083cecf6c85b5b617f882720596399afaf"
-GOLDEN_HIST_SHA = \
-    "343e87b79c6ece3608774a43160dccbb80ef214111bdb0f9f9c066ead77f9e80"
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 MEASURED_PHASES = {"setup", "encode", "exchange", "trunc_open"}
 
@@ -45,7 +37,7 @@ def _sha(arr, dtype):
 
 def test_proc_engine_matches_jit_golden():
     """api.fit over proc:4 -- 4 worker subprocesses, real sockets -- lands
-    on the exact pre-refactor bits (the PR's acceptance criterion)."""
+    on the exact pinned bits (tests/goldens.py)."""
     res = api.fit("smoke", "copml", "proc:4", key=0, iters=10, history=True)
     np.testing.assert_array_equal(
         np.asarray(res.weights, np.float64), np.asarray(GOLDEN_W))
@@ -130,6 +122,20 @@ def test_proc_rejects_fault_plans():
                                 min_available=10)
     with pytest.raises(ValueError, match="no FaultPlan replay"):
         api.fit("smoke_straggler", "copml", "proc:4", key=0, faults=plan)
+
+
+def test_proc_fails_fast_when_coordinator_holds_a_device(monkeypatch):
+    """On an accelerator host the coordinator holds the device, so the
+    workers could never load it: proc:N refuses at once, naming the
+    reason, instead of waiting out the spawn timeout."""
+    import time
+
+    from repro.launch.runtime import session
+    monkeypatch.setattr(session.jax, "default_backend", lambda: "tpu")
+    t0 = time.perf_counter()
+    with pytest.raises(RuntimeError, match="holds the tpu device"):
+        api.fit("smoke", "copml", "proc:2", key=0, iters=1, history=False)
+    assert time.perf_counter() - t0 < 5.0
 
 
 # ------------------------------------------- CLI listing == engine registry

@@ -1,0 +1,111 @@
+"""Compile rehearsal: the main-path Pallas kernels compile for a TPU v5e.
+
+Interpret mode (what the other kernel tests run on the CPU) accepts code
+that Mosaic refuses -- unaligned blocks, vector loads of per-client
+scalars, more VMEM than a kernel may use.  These tests compile each kernel
+for a described v5e chip (no chip attached) at the shapes the chip smoke
+run and the registered workloads use, with interpret mode off, and check
+that the compiled program holds the Mosaic kernel (`tpu_custom_call`).
+Nothing runs, so they say nothing about results or times.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker imports
+this file.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels import ops
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def mosaic(monkeypatch):
+    """Compile the kernels (interpret off) although the backend is the CPU,
+    with the persistent compilation cache off: entries written for a
+    described chip cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _compile_text(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=sharding)
+            for s in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+_KW = dict(q_eta=3, inv2k1=12345, k1=19, force_pallas=True)
+
+
+@pytest.mark.parametrize("n,m,d,c", [
+    (50, 8, 3073, 1),      # chip smoke: cifar10_case1 at m=128, K=16
+    (50, 564, 3073, 1),    # cifar10_case1 at the paper's m=9019
+    (50, 375, 5000, 1),    # gisette_case1: wide d shrinks bm
+    (13, 98, 24, 10),      # mnist10_like: class-batched (d, C)
+])
+def test_fused_step_compiles(one_chip, n, m, d, c):
+    txt = _compile_text(
+        lambda *a: ops.fused_step(*a, **_KW), one_chip,
+        (n, m, d), (n, d, c), (2,), (n,), (n,), (n,),
+        *[(n, d, c)] * 5)
+    assert "tpu_custom_call" in txt
+
+
+def test_coded_gradient_batched_compiles(one_chip):
+    txt = _compile_text(
+        lambda x, w, cf: ops.coded_gradient_batched(x, w, cf,
+                                                    force_pallas=True),
+        one_chip, (50, 16, 3073), (50, 3073), (2,))
+    assert "tpu_custom_call" in txt
+
+
+def test_coded_gradient_matrix_compiles(one_chip):
+    txt = _compile_text(
+        lambda x, w, cf: ops.coded_gradient_matrix(x, w, cf,
+                                                   force_pallas=True),
+        one_chip, (13, 98, 24), (13, 24, 10), (2,))
+    assert "tpu_custom_call" in txt
+
+
+def test_coded_gradient_single_client_compiles(one_chip):
+    txt = _compile_text(
+        lambda x, w, cf: ops.coded_gradient(x, w, cf, force_pallas=True),
+        one_chip, (256, 3073), (3073,), (2,))
+    assert "tpu_custom_call" in txt
+
+
+def test_modmatmul_compiles(one_chip):
+    txt = _compile_text(
+        lambda a, b: ops.modmatmul(a, b, force_pallas=True),
+        one_chip, (512, 512), (512, 512))
+    assert "tpu_custom_call" in txt
+
+
+def test_poly_eval_compiles(one_chip):
+    txt = _compile_text(
+        lambda z, cf: ops.poly_eval(z, cf, force_pallas=True),
+        one_chip, (50, 3073), (2,))
+    assert "tpu_custom_call" in txt

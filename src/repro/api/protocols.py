@@ -33,7 +33,7 @@ import time
 import jax
 import numpy as np
 
-from ..core import baselines, cost_model, secure_agg
+from ..core import baselines, cost_model, secure_agg, spans
 from ..core import objectives as objectives_mod
 from ..core.protocol import Copml, fused_mode
 from ..train import elastic
@@ -101,73 +101,79 @@ class Protocol:
 
     def fit(self, workload, engine="jit", *, key=0, iters=None, subset=None,
             history=True, faults=None) -> result_mod.TrainResult:
-        wl = workloads_mod.resolve(workload)
-        spec = engine_mod.parse(engine)
-        if spec.kind not in self.engines:
-            raise ValueError(
-                f"protocol {self.name!r} supports engines {self.engines}, "
-                f"not {spec.kind!r}")
-        if isinstance(key, int):
-            key = jax.random.PRNGKey(key)
-        iters = wl.iters if iters is None else int(iters)
-        if faults is not None:
-            if subset is not None:
+        with spans.fit():
+            wl = workloads_mod.resolve(workload)
+            spec = engine_mod.parse(engine)
+            if spec.kind not in self.engines:
                 raise ValueError(
-                    "faults= and subset= are mutually exclusive: the plan "
-                    "chooses each step's decode subset")
-            plan = self._resolve_plan(wl, iters, faults)
-            subset = None                    # the plan drives every step
-        else:
-            plan = None
-            if subset is None:
-                # the workload default only applies where it means something
-                subset = wl.subset if self.supports_subset else None
-            elif isinstance(subset, str):
-                if subset != "all":
-                    raise ValueError(f"subset must be None, 'all', or an "
-                                     f"iterable of client indices; got "
-                                     f"{subset!r}")
-                subset = None                     # force full decode
+                    f"protocol {self.name!r} supports engines {self.engines}, "
+                    f"not {spec.kind!r}")
+            if isinstance(key, int):
+                key = jax.random.PRNGKey(key)
+            iters = wl.iters if iters is None else int(iters)
+            if faults is not None:
+                if subset is not None:
+                    raise ValueError(
+                        "faults= and subset= are mutually exclusive: the plan "
+                        "chooses each step's decode subset")
+                plan = self._resolve_plan(wl, iters, faults)
+                subset = None                    # the plan drives every step
             else:
-                subset = tuple(subset) or None    # () also means full decode
-            if subset is not None and not self.supports_subset:
-                raise ValueError(
-                    f"protocol {self.name!r} has no straggler-subset "
-                    f"decoding; drop the subset argument")
+                plan = None
+                if subset is None:
+                    # the workload default only applies where it means
+                    # something
+                    subset = wl.subset if self.supports_subset else None
+                elif isinstance(subset, str):
+                    if subset != "all":
+                        raise ValueError(f"subset must be None, 'all', or an "
+                                         f"iterable of client indices; got "
+                                         f"{subset!r}")
+                    subset = None                     # force full decode
+                else:
+                    # () also means full decode
+                    subset = tuple(subset) or None
+                if subset is not None and not self.supports_subset:
+                    raise ValueError(
+                        f"protocol {self.name!r} has no straggler-subset "
+                        f"decoding; drop the subset argument")
 
-        t0 = time.perf_counter()
-        # plan is passed only when present: externally registered protocols
-        # written against the pre-fault 6-arg _run contract keep working
-        # for fault-free fits (docs/API.md extension example)
-        if plan is None:
-            out = self._run(wl, spec, key, iters, subset, history)
-        else:
-            out = self._run(wl, spec, key, iters, subset, history, plan)
-        # engines that MEASURE their communication (proc) return a 4th
-        # element; the in-process engines keep the 3-tuple contract
-        if len(out) == 4:
-            w, hist, state, measured = out
-        else:
-            w, hist, state = out
-            measured = None
-        w = np.asarray(jax.block_until_ready(w))
-        wall = time.perf_counter() - t0
+            t0 = time.perf_counter()
+            # plan is passed only when present: externally registered protocols
+            # written against the pre-fault 6-arg _run contract keep working
+            # for fault-free fits (docs/API.md extension example)
+            if plan is None:
+                out = self._run(wl, spec, key, iters, subset, history)
+            else:
+                out = self._run(wl, spec, key, iters, subset, history, plan)
+            # engines that MEASURE their communication (proc) return a 4th
+            # element; the in-process engines keep the 3-tuple contract
+            if len(out) == 4:
+                w, hist, state, measured = out
+            else:
+                w, hist, state = out
+                measured = None
+            w = np.asarray(jax.block_until_ready(w))
+            wall = time.perf_counter() - t0
 
-        hist = None if hist is None else np.asarray(hist)
-        x_eval, y_eval = wl.eval_set()
-        obj = wl.objective        # objective-defined scoring: accuracy for
-        #                           the logistic objectives, R^2 for linreg
-        acc = None if hist is None else np.asarray(
-            [obj.score(w_t, x_eval, y_eval) for w_t in hist])
-        return result_mod.TrainResult(
-            workload=wl.name, protocol=self.name, engine=spec.label,
-            iters=iters, weights=w, wall_time_s=wall, history=hist,
-            accuracy=acc,
-            final_accuracy=obj.score(w, x_eval, y_eval),
-            per_class_accuracy=obj.per_class_accuracy(w, x_eval, y_eval),
-            cost=self.cost(wl, iters), state=state,
-            availability=None if plan is None else plan.available.copy(),
-            measured_comm=measured)
+            with spans.span("finish"):
+                hist = None if hist is None else np.asarray(hist)
+                x_eval, y_eval = wl.eval_set()
+                obj = wl.objective    # objective-defined scoring: accuracy for
+                #                       the logistic objectives, R^2 for linreg
+                acc = None if hist is None else np.asarray(
+                    [obj.score(w_t, x_eval, y_eval) for w_t in hist])
+                return result_mod.TrainResult(
+                    workload=wl.name, protocol=self.name, engine=spec.label,
+                    iters=iters, weights=w, wall_time_s=wall, history=hist,
+                    accuracy=acc,
+                    final_accuracy=obj.score(w, x_eval, y_eval),
+                    per_class_accuracy=obj.per_class_accuracy(
+                        w, x_eval, y_eval),
+                    cost=self.cost(wl, iters), state=state,
+                    availability=(None if plan is None
+                                  else plan.available.copy()),
+                    measured_comm=measured)
 
     def _resolve_plan(self, wl, iters: int, faults) -> faults_mod.FaultPlan:
         """Check a FaultPlan against this protocol and workload, truncate

@@ -40,7 +40,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from . import (field, lagrange, meshutil, mpc, objectives, quantize, shamir,
-               truncation)
+               spans, truncation)
 from .labels import Coded, Opened, Public, Share
 
 
@@ -208,58 +208,67 @@ class Copml:
         loop reused keys[j] for both, correlating their masks).
         """
         cfg, n = self.cfg, self.cfg.n_clients
-        keys = jax.random.split(key, 6)
+        with spans.span("setup", m=self.m, d=self.d, n=n):
+            keys = jax.random.split(key, 6)
 
-        # Phase 1 (LOCAL): quantize into F_p -- one call over all rows.
-        # The objective owns the target embedding (binary {0,1} passes
-        # through; multiclass one-hots integer labels into (m, C)).
-        xq = quantize.quantize(
-            jnp.concatenate([jnp.asarray(x) for x in client_xs], axis=0),
-            cfg.lx)                                           # (m, d)
-        targets = self.obj.prepare_targets(
-            np.concatenate([np.asarray(y) for y in client_ys], axis=0))
-        yq = quantize.quantize(jnp.asarray(targets, jnp.float32), cfg.lg)
-        # (m,) + out_shape
+            # Phase 1 (LOCAL): quantize into F_p -- one call over all rows.
+            # The objective owns the target embedding (binary {0,1} passes
+            # through; multiclass one-hots integer labels into (m, C)).
+            xq = quantize.quantize(
+                jnp.concatenate([jnp.asarray(x) for x in client_xs], axis=0),
+                cfg.lx)                                           # (m, d)
+            targets = self.obj.prepare_targets(
+                np.concatenate([np.asarray(y) for y in client_ys], axis=0))
+            yq = quantize.quantize(jnp.asarray(targets, jnp.float32), cfg.lg)
+            # (m,) + out_shape
 
-        # Phase 2a (EXCHANGE): Shamir-share every client's data (batched)
-        x_shares = shamir.share(keys[0], xq, cfg.t, n, self.lambdas)
-        y_shares = shamir.share(keys[1], yq, cfg.t, n, self.lambdas)
-        # (N, m, d) / (N, m) + out_shape
+            # Phase 2a (EXCHANGE): Shamir-share every client's data (batched)
+            with spans.span("setup.share"):
+                x_shares = shamir.share(keys[0], xq, cfg.t, n, self.lambdas)
+                y_shares = shamir.share(keys[1], yq, cfg.t, n, self.lambdas)
+            # (N, m, d) / (N, m) + out_shape
 
-        # Phase 2b (LOCAL on shares): partition rows into K blocks
-        blocks, self.pad = jax.vmap(
-            lambda s: lagrange.partition_rows(s, cfg.k)[0])(x_shares), 0
-        # blocks: (N, K, mk, d)
+            with spans.span("setup.encode"):
+                # Phase 2b (LOCAL on shares): partition rows into K blocks
+                blocks, self.pad = jax.vmap(lambda s: lagrange.partition_rows(
+                    s, cfg.k)[0])(x_shares), 0
+                # blocks: (N, K, mk, d)
 
-        # shared random masks Z_{K+1..K+T} (offline randomness, fn. 3)
-        z = field.random_field(keys[2], (cfg.t, blocks.shape[2], self.d))
-        z_shares = shamir.share(keys[3], z, cfg.t, n, self.lambdas)
-        # (N, T, mk, d)
+                # shared random masks Z_{K+1..K+T} (offline randomness,
+                # fn. 3)
+                z = field.random_field(keys[2],
+                                       (cfg.t, blocks.shape[2], self.d))
+                z_shares = shamir.share(keys[3], z, cfg.t, n, self.lambdas)
+                # (N, T, mk, d)
 
-        # Phase 2c (LOCAL): LCC-encode the shares; (EXCHANGE): reconstruct
-        # each client's coded slice from T+1 shares (fn. 4: subgrouping)
-        enc = jax.vmap(lambda b, zz: lagrange.lcc_encode(
-            b, zz, self.alphas, self.betas))(blocks, z_shares)
-        # enc: (N_holder, N_owner, mk, d); reconstruct over holders
-        coded_x = shamir.reconstruct(enc, cfg.t, self.lambdas)  # (N, mk, d)
+                # Phase 2c (LOCAL): LCC-encode the shares; (EXCHANGE):
+                # reconstruct each client's coded slice from T+1 shares
+                # (fn. 4: subgrouping)
+                enc = jax.vmap(lambda b, zz: lagrange.lcc_encode(
+                    b, zz, self.alphas, self.betas))(blocks, z_shares)
+                # enc: (N_holder, N_owner, mk, d); reconstruct over holders
+                coded_x = shamir.reconstruct(enc, cfg.t,
+                                             self.lambdas)  # (N, mk, d)
 
-        # Phase 2d: X^T y via one secure matmul (degree reduction included);
-        # a matrix objective contracts against all C target columns at once
-        y_mat = y_shares if self.out_shape else y_shares[..., None]
-        xty_shares = self._mul(
-            keys[4],
-            jnp.swapaxes(x_shares, 1, 2), y_mat,
-            cfg.t, matmul=True, points=self.lambdas)     # (N, d, C')
-        if not self.out_shape:
-            xty_shares = xty_shares[..., 0]              # (N,) + w_shape
+            # Phase 2d: X^T y via one secure matmul (degree reduction
+            # included); a matrix objective contracts against all C target
+            # columns at once
+            with spans.span("setup.xty"):
+                y_mat = y_shares if self.out_shape else y_shares[..., None]
+                xty_shares = self._mul(
+                    keys[4],
+                    jnp.swapaxes(x_shares, 1, 2), y_mat,
+                    cfg.t, matmul=True, points=self.lambdas)     # (N, d, C')
+            if not self.out_shape:
+                xty_shares = xty_shares[..., 0]              # (N,) + w_shape
 
-        # model init within MPC: w^(0) = 0 shared
-        w_shares = shamir.share(
-            keys[5], jnp.zeros(self.w_shape, field.FIELD_DTYPE),
-            cfg.t, n, self.lambdas)
-        return CopmlState(w_shares=w_shares, coded_x=coded_x,
-                          xty_shares=xty_shares,
-                          step=jnp.asarray(0, jnp.int32))
+            # model init within MPC: w^(0) = 0 shared
+            w_shares = shamir.share(
+                keys[5], jnp.zeros(self.w_shape, field.FIELD_DTYPE),
+                cfg.t, n, self.lambdas)
+            return CopmlState(w_shares=w_shares, coded_x=coded_x,
+                              xty_shares=xty_shares,
+                              step=jnp.asarray(0, jnp.int32))
 
     # ------------------------------------------------------- one GD iteration
 
@@ -269,30 +278,33 @@ class Copml:
         LOCAL on shares + EXCHANGE to reconstruct w~_j at client j.
         v(beta_k) = w for all k in [K]; T random vectors v_k pad the tail.
         """
-        cfg, n = self.cfg, self.cfg.n_clients
-        kv, ks = jax.random.split(key)
-        # distinct keys: drawing v and its sharing polynomial from the same
-        # key makes the sharing coefficients EQUAL v (same threefry stream),
-        # letting any single share reveal the mask
-        v = field.random_field(kv, (cfg.t,) + self.w_shape)
-        v_shares = shamir.share(ks, v, cfg.t, n, self.lambdas)  # (N,T)+w_shape
-        # LCC encoding is elementwise-linear: flatten the trailing model
-        # dims so vector and matrix models share one encode path (dw = d
-        # for the vector objectives -- these reshapes are no-ops there)
-        w_flat = w_shares.reshape(n, self.dw)
-        v_flat = v_shares.reshape(n, cfg.t, self.dw)
-        blocks = jnp.broadcast_to(
-            w_flat[:, None], (n, cfg.k, self.dw))                # same w in K slots
-        enc = jax.vmap(lambda b, vv: lagrange.lcc_encode(
-            b[:, None, :], vv[:, None, :], self.alphas, self.betas
-        )[:, 0, :])(blocks, v_flat)                              # (N_holder,N_owner,dw)
-        # keep enc holder-sharded: otherwise GSPMD all-gathers every
-        # holder's (K+T, d) limb stack (~1 GiB/step at N=256, the dominant
-        # collective of the baseline -- EXPERIMENTS.md Perf, COPML iter 2);
-        # reconstruct from ALL N shares so the contraction reduce-scatters.
-        enc = meshutil.maybe_constrain(enc, meshutil.CLIENTS)
-        out = shamir.reconstruct(enc, cfg.t, self.lambdas, subset="all")
-        return meshutil.maybe_constrain(out, meshutil.CLIENTS)   # (N, d)
+        with jax.named_scope("copml.encode_model"):
+            cfg, n = self.cfg, self.cfg.n_clients
+            kv, ks = jax.random.split(key)
+            # distinct keys: drawing v and its sharing polynomial from the
+            # same key makes the sharing coefficients EQUAL v (same
+            # threefry stream), letting any single share reveal the mask
+            v = field.random_field(kv, (cfg.t,) + self.w_shape)
+            v_shares = shamir.share(ks, v, cfg.t, n,
+                                    self.lambdas)       # (N,T)+w_shape
+            # LCC encoding is elementwise-linear: flatten the trailing model
+            # dims so vector and matrix models share one encode path (dw = d
+            # for the vector objectives -- these reshapes are no-ops there)
+            w_flat = w_shares.reshape(n, self.dw)
+            v_flat = v_shares.reshape(n, cfg.t, self.dw)
+            blocks = jnp.broadcast_to(
+                w_flat[:, None], (n, cfg.k, self.dw))   # same w in K slots
+            enc = jax.vmap(lambda b, vv: lagrange.lcc_encode(
+                b[:, None, :], vv[:, None, :], self.alphas, self.betas
+            )[:, 0, :])(blocks, v_flat)                 # (N_holder,N_owner,dw)
+            # keep enc holder-sharded: otherwise GSPMD all-gathers every
+            # holder's (K+T, d) limb stack (~1 GiB/step at N=256, the
+            # dominant collective of the baseline -- EXPERIMENTS.md Perf,
+            # COPML iter 2); reconstruct from ALL N shares so the
+            # contraction reduce-scatters.
+            enc = meshutil.maybe_constrain(enc, meshutil.CLIENTS)
+            out = shamir.reconstruct(enc, cfg.t, self.lambdas, subset="all")
+            return meshutil.maybe_constrain(out, meshutil.CLIENTS)  # (N, d)
 
     def local_gradient(self, coded_x: Coded, coded_w: Coded) -> Coded:
         """Phase 3 (LOCAL, the hot loop): f(X~_i, w~_i) = X~_i^T ghat(X~_i w~_i).
@@ -416,39 +428,42 @@ class Copml:
             dfull = jnp.zeros((n,), jnp.int32).at[subset_idx].set(dvec)
 
         c = self.obj.n_outputs
-        mix = shamir.share(
-            kf, jnp.zeros((n,) + self.w_shape, field.FIELD_DTYPE),
-            cfg.t, n, self.lambdas)                    # (N_h, N_o) + w_shape
-        base = jax.vmap(lambda mh: field.matmul(
-            dfull[None], mh.reshape(n, self.dw))[0])(mix)       # (N_h, dw)
+        with jax.named_scope("copml.step_rand"):
+            mix = shamir.share(
+                kf, jnp.zeros((n,) + self.w_shape, field.FIELD_DTYPE),
+                cfg.t, n, self.lambdas)                # (N_h, N_o) + w_shape
+            base = jax.vmap(lambda mh: field.matmul(
+                dfull[None], mh.reshape(n, self.dw))[0])(mix)   # (N_h, dw)
 
-        r_sh, r0_sh = truncation.trunc_pr_randomness(
-            kt, self.w_shape, self.k1, self.k2,
-            lambda k, s: shamir.share(k, s, cfg.t, n, self.lambdas))
-        bias = 1 << (self.k2 - 1)
-        radd = field.add(r_sh, jnp.full_like(r_sh, bias))
+            r_sh, r0_sh = truncation.trunc_pr_randomness(
+                kt, self.w_shape, self.k1, self.k2,
+                lambda k, s: shamir.share(k, s, cfg.t, n, self.lambdas))
+            bias = 1 << (self.k2 - 1)
+            radd = field.add(r_sh, jnp.full_like(r_sh, bias))
 
-        # reconstruct's default open subset: first T+1 holders, zero-padded
-        rvec_np = np.zeros(n, np.int32)
-        rvec_np[: cfg.t + 1] = shamir.recon_weights(
-            self.lambdas, tuple(range(cfg.t + 1))).astype(np.int32)
-        rvec = jnp.asarray(rvec_np)
+            # reconstruct's default open subset: first T+1 holders,
+            # zero-padded
+            rvec_np = np.zeros(n, np.int32)
+            rvec_np[: cfg.t + 1] = shamir.recon_weights(
+                self.lambdas, tuple(range(cfg.t + 1))).astype(np.int32)
+            rvec = jnp.asarray(rvec_np)
 
         adv_off = jnp.zeros((n,), jnp.int32) if adv is None else \
             jnp.where(adv, jnp.asarray(ADV_OFFSET, jnp.int32), 0)
 
         mat = (n, self.d, c)
-        _, new_w = kernel_ops.fused_step(
-            state.coded_x,
-            coded_w.reshape(mat),
-            self.poly_coeffs, adv_off, dfull, rvec,
-            base.reshape(mat),
-            state.xty_shares.reshape(mat),
-            state.w_shares.reshape(mat),
-            radd.reshape(mat),
-            r0_sh.reshape(mat),
-            q_eta=self.q_eta, inv2k1=field.host_inv(1 << self.k1),
-            k1=self.k1, force_pallas=self.fused_mode == "kernel")
+        with jax.named_scope("copml.fused_step"):
+            _, new_w = kernel_ops.fused_step(
+                state.coded_x,
+                coded_w.reshape(mat),
+                self.poly_coeffs, adv_off, dfull, rvec,
+                base.reshape(mat),
+                state.xty_shares.reshape(mat),
+                state.w_shares.reshape(mat),
+                radd.reshape(mat),
+                r0_sh.reshape(mat),
+                q_eta=self.q_eta, inv2k1=field.host_inv(1 << self.k1),
+                k1=self.k1, force_pallas=self.fused_mode == "kernel")
         new_w = new_w.reshape((n,) + self.w_shape)
         return dataclasses.replace(state, w_shares=new_w,
                                    step=state.step + 1)
@@ -553,8 +568,9 @@ class Copml:
         subset = None if subset is None else tuple(subset)
         faults = self._fault_xs(step_subsets, adversaries, int(iters),
                                 subset)
-        state, hist = _scan_iterations(self, ki, state, int(iters), subset,
-                                       bool(history), faults)
+        with spans.span("loop", iters=int(iters)):
+            state, hist = _scan_iterations(self, ki, state, int(iters),
+                                           subset, bool(history), faults)
         w = self.open_model(state)
         return (state, w, hist) if history else (state, w)
 

@@ -1,0 +1,91 @@
+"""The program's own spans and scopes: host spans of one fit in the
+profiler's trace, device scopes in the compiled loop's op metadata, and
+nothing recorded outside a profiler session."""
+
+import glob
+
+import jax
+import pytest
+from jax.profiler import ProfileData
+
+from repro import api
+from repro.core import protocol, spans
+
+FIT_SPANS = {"repro:fit", "repro:setup", "repro:setup.share",
+             "repro:setup.encode", "repro:setup.xty", "repro:loop",
+             "repro:finish"}
+LOOP_SCOPES = ("copml.encode_model", "copml.step_rand", "copml.fused_step")
+
+
+def host_spans(log_dir) -> list:
+    """[(name, start, end, args)] of the `repro:*` host events."""
+    path, = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+             dict(ev.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith("repro:")]
+
+
+@pytest.fixture(scope="module")
+def traced_fits(tmp_path_factory):
+    """The host spans of two traced fits, by fit id."""
+    log_dir = tmp_path_factory.mktemp("trace")
+    api.fit("smoke", "copml", "jit", iters=2, history=False)   # compile
+    with jax.profiler.trace(str(log_dir)):
+        for _ in range(2):
+            api.fit("smoke", "copml", "jit", iters=2, history=False)
+    fits = {}
+    for name, s, e, args in host_spans(log_dir):
+        fits.setdefault(args["fit"], []).append((name, s, e, args))
+    return fits
+
+
+def test_each_fit_has_its_own_id(traced_fits):
+    assert len(traced_fits) == 2 and 0 not in traced_fits
+    for spans_of_fit in traced_fits.values():
+        assert sorted(n for n, *_ in spans_of_fit) == sorted(FIT_SPANS)
+
+
+def test_every_span_nests_in_its_fit(traced_fits):
+    wl = api.get_workload("smoke")
+    for fit_id, spans_of_fit in traced_fits.items():
+        by_name = {n: (s, e, a) for n, s, e, a in spans_of_fit}
+        lo, hi, _ = by_name["repro:fit"]
+        for name, (s, e, _) in by_name.items():
+            assert lo <= s <= e <= hi, name
+        assert by_name["repro:loop"][2] == {"fit": fit_id, "iters": 2}
+        setup = by_name["repro:setup"]
+        assert setup[2] == {"fit": fit_id, "m": wl.m, "d": wl.d,
+                            "n": wl.n_clients}
+        for part in ("share", "encode", "xty"):
+            s, e, _ = by_name[f"repro:setup.{part}"]
+            assert setup[0] <= s <= e <= setup[1], part
+
+
+def test_the_loop_program_names_its_scopes():
+    wl = api.get_workload("smoke")
+    proto = protocol.Copml(wl.cfg, wl.m, wl.d)
+    cx, cy = wl.client_data()
+    state = proto.setup(jax.random.PRNGKey(0), cx, cy)
+    hlo = protocol._scan_iterations.lower(
+        proto, jax.random.PRNGKey(1), state, 2, None, False,
+        None).compile().as_text()
+    for scope in LOOP_SCOPES:
+        assert f"/{scope}/" in hlo, scope
+
+
+def test_a_span_outside_a_session_records_nothing(tmp_path):
+    outside = spans.span("outside", x=1)
+    assert isinstance(outside, jax.profiler.TraceAnnotation)
+    with outside:
+        pass
+    late = spans.span("late")
+    late.__enter__()                 # opened before the session starts
+    with jax.profiler.trace(str(tmp_path)):
+        late.__exit__(None, None, None)
+        with spans.span("inside"):
+            pass
+    assert [(n, a) for n, _, _, a in host_spans(tmp_path)] == \
+        [("repro:inside", {"fit": 0})]

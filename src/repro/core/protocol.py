@@ -199,76 +199,85 @@ class Copml:
 
         client_xs[j]: (m_j, d) float arrays; client_ys[j]: (m_j,) in {0,1}.
 
-        Fully batched: clients' rows are stacked once and every phase is one
-        vectorized field op -- no per-client Python loop.  Sharing the
-        stacked rows in a single shamir.share call is distribution-identical
-        to per-client sharing (the masking polynomial draws independent
-        randomness per element either way) and collapses N share matmuls
-        into one.  It also gives X and y sharing independent keys (the old
-        loop reused keys[j] for both, correlating their masks).
+        The host stacks the clients' rows and embeds the targets (numpy),
+        moves both to the device once, and the field work runs as ONE
+        compiled program (`_setup_program`), shared by every instance of
+        the same workload.  The returned state is not waited for, so the
+        caller can dispatch the training loop at once.
         """
-        cfg, n = self.cfg, self.cfg.n_clients
-        with spans.span("setup", m=self.m, d=self.d, n=n):
-            keys = jax.random.split(key, 6)
-
-            # Phase 1 (LOCAL): quantize into F_p -- one call over all rows.
-            # The objective owns the target embedding (binary {0,1} passes
-            # through; multiclass one-hots integer labels into (m, C)).
-            xq = quantize.quantize(
-                jnp.concatenate([jnp.asarray(x) for x in client_xs], axis=0),
-                cfg.lx)                                           # (m, d)
+        self.pad = 0
+        with spans.span("setup", m=self.m, d=self.d, n=self.cfg.n_clients):
+            x = np.concatenate([np.asarray(x) for x in client_xs], axis=0)
+            # the objective owns the target embedding (binary {0,1} passes
+            # through; multiclass one-hots integer labels into (m, C))
             targets = self.obj.prepare_targets(
                 np.concatenate([np.asarray(y) for y in client_ys], axis=0))
-            yq = quantize.quantize(jnp.asarray(targets, jnp.float32), cfg.lg)
-            # (m,) + out_shape
+            return _setup_program(self.cfg, self.obj, self.m, self.d, key,
+                                  jnp.asarray(x),
+                                  jnp.asarray(targets, jnp.float32))
 
-            # Phase 2a (EXCHANGE): Shamir-share every client's data (batched)
-            with spans.span("setup.share"):
-                x_shares = shamir.share(keys[0], xq, cfg.t, n, self.lambdas)
-                y_shares = shamir.share(keys[1], yq, cfg.t, n, self.lambdas)
-            # (N, m, d) / (N, m) + out_shape
+    def _setup_phases(self, key, x, targets) -> CopmlState:
+        """The field work of `setup` on the stacked rows x (m, d) and
+        targets (m,) + out_shape: the body of `_setup_program`.
 
-            with spans.span("setup.encode"):
-                # Phase 2b (LOCAL on shares): partition rows into K blocks
-                blocks, self.pad = jax.vmap(lambda s: lagrange.partition_rows(
-                    s, cfg.k)[0])(x_shares), 0
-                # blocks: (N, K, mk, d)
+        Fully batched: every phase is one vectorized field op -- no
+        per-client Python loop.  Sharing the stacked rows in a single
+        shamir.share call is distribution-identical to per-client sharing
+        (the masking polynomial draws independent randomness per element
+        either way) and collapses N share matmuls into one.  It also gives
+        X and y sharing independent keys.
+        """
+        cfg, n = self.cfg, self.cfg.n_clients
+        keys = jax.random.split(key, 6)
 
-                # shared random masks Z_{K+1..K+T} (offline randomness,
-                # fn. 3)
-                z = field.random_field(keys[2],
-                                       (cfg.t, blocks.shape[2], self.d))
-                z_shares = shamir.share(keys[3], z, cfg.t, n, self.lambdas)
-                # (N, T, mk, d)
+        # Phase 1 (LOCAL): quantize into F_p -- one call over all rows
+        xq = quantize.quantize(x, cfg.lx)                         # (m, d)
+        yq = quantize.quantize(targets, cfg.lg)         # (m,) + out_shape
 
-                # Phase 2c (LOCAL): LCC-encode the shares; (EXCHANGE):
-                # reconstruct each client's coded slice from T+1 shares
-                # (fn. 4: subgrouping)
-                enc = jax.vmap(lambda b, zz: lagrange.lcc_encode(
-                    b, zz, self.alphas, self.betas))(blocks, z_shares)
-                # enc: (N_holder, N_owner, mk, d); reconstruct over holders
-                coded_x = shamir.reconstruct(enc, cfg.t,
-                                             self.lambdas)  # (N, mk, d)
+        # Phase 2a (EXCHANGE): Shamir-share every client's data (batched)
+        with jax.named_scope("copml.setup.share"):
+            x_shares = shamir.share(keys[0], xq, cfg.t, n, self.lambdas)
+            y_shares = shamir.share(keys[1], yq, cfg.t, n, self.lambdas)
+        # (N, m, d) / (N, m) + out_shape
 
-            # Phase 2d: X^T y via one secure matmul (degree reduction
-            # included); a matrix objective contracts against all C target
-            # columns at once
-            with spans.span("setup.xty"):
-                y_mat = y_shares if self.out_shape else y_shares[..., None]
-                xty_shares = self._mul(
-                    keys[4],
-                    jnp.swapaxes(x_shares, 1, 2), y_mat,
-                    cfg.t, matmul=True, points=self.lambdas)     # (N, d, C')
-            if not self.out_shape:
-                xty_shares = xty_shares[..., 0]              # (N,) + w_shape
+        with jax.named_scope("copml.setup.encode"):
+            # Phase 2b (LOCAL on shares): partition rows into K blocks
+            blocks = jax.vmap(lambda s: lagrange.partition_rows(
+                s, cfg.k)[0])(x_shares)                   # (N, K, mk, d)
 
-            # model init within MPC: w^(0) = 0 shared
-            w_shares = shamir.share(
-                keys[5], jnp.zeros(self.w_shape, field.FIELD_DTYPE),
-                cfg.t, n, self.lambdas)
-            return CopmlState(w_shares=w_shares, coded_x=coded_x,
-                              xty_shares=xty_shares,
-                              step=jnp.asarray(0, jnp.int32))
+            # shared random masks Z_{K+1..K+T} (offline randomness, fn. 3)
+            z = field.random_field(keys[2], (cfg.t, blocks.shape[2], self.d))
+            z_shares = shamir.share(keys[3], z, cfg.t, n, self.lambdas)
+            # (N, T, mk, d)
+
+            # Phase 2c (LOCAL): LCC-encode the shares; (EXCHANGE):
+            # reconstruct each client's coded slice from T+1 shares
+            # (fn. 4: subgrouping)
+            enc = jax.vmap(lambda b, zz: lagrange.lcc_encode(
+                b, zz, self.alphas, self.betas))(blocks, z_shares)
+            # enc: (N_holder, N_owner, mk, d); reconstruct over holders
+            coded_x = shamir.reconstruct(enc, cfg.t,
+                                         self.lambdas)        # (N, mk, d)
+
+        # Phase 2d: X^T y via one secure matmul (degree reduction
+        # included); a matrix objective contracts against all C target
+        # columns at once
+        with jax.named_scope("copml.setup.xty"):
+            y_mat = y_shares if self.out_shape else y_shares[..., None]
+            xty_shares = self._mul(
+                keys[4],
+                jnp.swapaxes(x_shares, 1, 2), y_mat,
+                cfg.t, matmul=True, points=self.lambdas)     # (N, d, C')
+        if not self.out_shape:
+            xty_shares = xty_shares[..., 0]                  # (N,) + w_shape
+
+        # model init within MPC: w^(0) = 0 shared
+        w_shares = shamir.share(
+            keys[5], jnp.zeros(self.w_shape, field.FIELD_DTYPE),
+            cfg.t, n, self.lambdas)
+        return CopmlState(w_shares=w_shares, coded_x=coded_x,
+                          xty_shares=xty_shares,
+                          step=jnp.asarray(0, jnp.int32))
 
     # ------------------------------------------------------- one GD iteration
 
@@ -959,6 +968,20 @@ def _pad_clients(arr, n_pad: int):
         return arr
     pad = jnp.zeros((n_pad - n,) + arr.shape[1:], arr.dtype)
     return jnp.concatenate([arr, pad], axis=0)
+
+
+@partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _setup_program(cfg: CopmlConfig, objective, m: int, d: int, key, x,
+                   targets) -> CopmlState:
+    """Copml.setup's field work (Phases 1-2) as one XLA program.
+
+    The static arguments are the values that define the program, not a
+    Copml instance, so every instance of one workload shares the
+    executable.  Its phases are named by the device scopes
+    `copml.setup.share`, `copml.setup.encode` and `copml.setup.xty`.
+    Under `jax.disable_jit()` the same body runs op by op.
+    """
+    return Copml(cfg, m, d, objective)._setup_phases(key, x, targets)
 
 
 @partial(jax.jit, static_argnames=("proto", "iters", "subset", "history"))

@@ -9,11 +9,12 @@ phase the host was in.  Outside a profiler session a span records nothing
 and costs only the context manager.
 
 Host spans: `repro:fit` (Protocol.fit), `repro:setup` (Copml.setup, with
-`m`, `d`, `n`) and inside it `repro:setup.share`, `repro:setup.encode`,
-`repro:setup.xty`; `repro:loop` (the jit engine's compiled loop, with
-`iters`); `repro:finish` (scoring and the TrainResult).  The compiled loop
-names its device work with `jax.named_scope` instead, which lands in the
-ops' `op_name` metadata: `copml.encode_model`, `copml.step_rand`,
+`m`, `d`, `n`: host preparation and the dispatch of the compiled setup
+program), `repro:loop` (the jit engine's compiled loop, with `iters`);
+`repro:finish` (scoring and the TrainResult).  The compiled programs name
+their device work with `jax.named_scope` instead, which lands in the ops'
+`op_name` metadata: setup's `copml.setup.share`, `copml.setup.encode`,
+`copml.setup.xty`, and the loop's `copml.encode_model`, `copml.step_rand`,
 `copml.fused_step`.
 """
 

@@ -1,6 +1,6 @@
 """The program's own spans and scopes: host spans of one fit in the
-profiler's trace, device scopes in the compiled loop's op metadata, and
-nothing recorded outside a profiler session."""
+profiler's trace, device scopes in the compiled setup's and loop's op
+metadata, and nothing recorded outside a profiler session."""
 
 import glob
 
@@ -11,9 +11,8 @@ from jax.profiler import ProfileData
 from repro import api
 from repro.core import protocol, spans
 
-FIT_SPANS = {"repro:fit", "repro:setup", "repro:setup.share",
-             "repro:setup.encode", "repro:setup.xty", "repro:loop",
-             "repro:finish"}
+FIT_SPANS = {"repro:fit", "repro:setup", "repro:loop", "repro:finish"}
+SETUP_SCOPES = ("copml.setup.share", "copml.setup.encode", "copml.setup.xty")
 LOOP_SCOPES = ("copml.encode_model", "copml.step_rand", "copml.fused_step")
 
 
@@ -59,9 +58,7 @@ def test_every_span_nests_in_its_fit(traced_fits):
         setup = by_name["repro:setup"]
         assert setup[2] == {"fit": fit_id, "m": wl.m, "d": wl.d,
                             "n": wl.n_clients}
-        for part in ("share", "encode", "xty"):
-            s, e, _ = by_name[f"repro:setup.{part}"]
-            assert setup[0] <= s <= e <= setup[1], part
+        assert setup[1] <= by_name["repro:loop"][0]
 
 
 def test_the_loop_program_names_its_scopes():
@@ -74,6 +71,17 @@ def test_the_loop_program_names_its_scopes():
         None).compile().as_text()
     for scope in LOOP_SCOPES:
         assert f"/{scope}/" in hlo, scope
+
+
+def test_the_setup_program_names_its_scopes():
+    wl = api.get_workload("smoke")
+    x, y, _, _ = wl.data()
+    hlo = protocol._setup_program.lower(
+        wl.cfg, wl.objective, wl.m, wl.d,
+        jax.random.PRNGKey(0), x, y).compile().as_text()
+    for scope in SETUP_SCOPES:
+        assert f"/{scope}/" in hlo, scope
+    assert not any(f"/{scope}/" in hlo for scope in LOOP_SCOPES)
 
 
 def test_a_span_outside_a_session_records_nothing(tmp_path):

@@ -109,6 +109,8 @@ EFFECTS = {
     # --- Shamir sharing ----------------------------------------------------
     "repro.core.shamir.share": {
         "kind": "source", "labels": frozenset({SHARE, FIELD, REDUCED})},
+    "repro.core.shamir.share_with": {
+        "kind": "source", "labels": frozenset({SHARE, FIELD, REDUCED})},
     "repro.core.shamir.share_batch": {
         "kind": "source", "labels": frozenset({SHARE, FIELD, REDUCED})},
     "repro.core.shamir.reshare": {

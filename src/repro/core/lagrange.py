@@ -77,15 +77,18 @@ def lcc_decode(evals, subset_alphas: Sequence[int], betas: Sequence[int], k: int
     return out.reshape((k,) + evals.shape[1:])
 
 
-def partition_rows(x, k: int):
-    """Split rows into K equal blocks, padding with zero rows if needed.
+def partition_rows(x, k: int, axis: int = 0):
+    """Split the rows on `axis` into K equal blocks, padding with zero rows
+    if needed.
 
-    Returns (blocks (K, m_pad/K, d), pad_rows).
+    Returns (blocks, pad_rows): blocks has x's shape with the rows axis
+    split into (K, m_pad/K).
     """
-    m = x.shape[0]
+    m = x.shape[axis]
     per = -(-m // k)
     pad = per * k - m
     if pad:
-        x = jnp.concatenate(
-            [x, jnp.zeros((pad,) + x.shape[1:], x.dtype)], axis=0)
-    return x.reshape((k, per) + x.shape[1:]), pad
+        widths = [(0, 0)] * x.ndim
+        widths[axis] = (0, pad)
+        x = jnp.pad(x, widths)
+    return x.reshape(x.shape[:axis] + (k, per) + x.shape[axis + 1:]), pad

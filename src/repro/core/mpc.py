@@ -47,7 +47,7 @@ def add_public(xs: Share, c: int) -> Share:
     return field.add(xs, jnp.full_like(xs, int(c) % field.P))
 
 
-def _local_product(xs, ys, matmul: bool):
+def local_product(xs, ys, matmul: bool):
     if matmul:
         return jax.vmap(field.matmul)(xs, ys)
     return field.mul(xs, ys)
@@ -59,10 +59,14 @@ def mul_bgw(key, xs: Share, ys: Share, t: int, *, matmul: bool = False,
 
     Requires N >= 2T+1.  If matmul=True, xs:(N,A,B) @ ys:(N,B,C).
     """
-    n = xs.shape[0]
-    assert n >= 2 * t + 1, "BGW needs N >= 2T+1"
-    prod = _local_product(xs, ys, matmul)
-    return shamir.reshare(key, prod, t, n, points)
+    assert xs.shape[0] >= 2 * t + 1, "BGW needs N >= 2T+1"
+    return reduce_bgw(key, local_product(xs, ys, matmul), t, points)
+
+
+def reduce_bgw(key, prod: Share, t: int,
+               points: Sequence[int] | None = None) -> Share:
+    """BGW's degree reduction of degree-2T product shares: re-share."""
+    return shamir.reshare(key, prod, t, prod.shape[0], points)
 
 
 def mul_bh08(key, xs: Share, ys: Share, t: int, *, matmul: bool = False,
@@ -73,11 +77,19 @@ def mul_bh08(key, xs: Share, ys: Share, t: int, *, matmul: bool = False,
     Online:  open d = x*y - rho from degree-2T shares (needs 2T+1 of them),
              output [rho]_T + d  (local add of a now-public value).
     """
-    n = xs.shape[0]
-    assert n >= 2 * t + 1, "BH08 needs N >= 2T+1 to open the 2T-degree mask"
+    assert xs.shape[0] >= 2 * t + 1, \
+        "BH08 needs N >= 2T+1 to open the 2T-degree mask"
+    return reduce_bh08(key, local_product(xs, ys, matmul), t, points)
+
+
+def reduce_bh08(key, prod: Share, t: int,
+                points: Sequence[int] | None = None) -> Share:
+    """BH08's degree reduction of degree-2T product shares (N, ...): mask
+    with the offline pair, open, re-mask.  A sum of local products may be
+    reduced once, as setup does for X^T y."""
+    n = prod.shape[0]
     if points is None:
         points = shamir.default_eval_points(n)
-    prod = _local_product(xs, ys, matmul)  # (N, ...) degree-2T shares
     k_rho, k_t, k_2t = jax.random.split(key, 3)
     rho = field.random_field(k_rho, prod.shape[1:])
     rho_t = shamir.share(k_t, rho, t, n, points)

@@ -132,6 +132,21 @@ def case2_params(n: int, r: int = 1) -> tuple:
     return k, t
 
 
+# Setup streams its LCC encode over chunks of the row index within a block.
+# One chunk's encode tensor, (N holders, N owners, rows, d) int32, may take
+# at most this many bytes; the limb products of its field matmul take 16
+# times as much in f32 (4 GiB), which with setup's whole-size random draws
+# and X~ leaves the training loop room on a 16 GB TPU v5e.
+SETUP_ENCODE_CHUNK_BYTES = 1 << 28
+
+
+def setup_chunks(n: int, mk: int, d: int) -> int:
+    """How many chunks setup encodes the mk rows of each block in: the
+    fewest whose encode tensors fit SETUP_ENCODE_CHUNK_BYTES."""
+    row_bytes = n * n * d * np.dtype(np.int32).itemsize
+    return -(-mk // max(1, SETUP_ENCODE_CHUNK_BYTES // row_bytes))
+
+
 def derive_update_constants(cfg: CopmlConfig, m: int) -> tuple:
     """(q_eta, e, k1, k2): eta/m ~= q_eta / 2^e, q_eta a small public int.
 
@@ -186,7 +201,8 @@ class Copml:
             cfg, m)
         # field coefficients of ghat at output scale lg given input scale lz
         self.poly_coeffs = self.obj.field_coeffs(cfg)
-        self._mul = mpc.mul_bh08 if cfg.mpc_mul == "bh08" else mpc.mul_bgw
+        self._reduce = mpc.reduce_bh08 if cfg.mpc_mul == "bh08" \
+            else mpc.reduce_bgw
         # the megakernel gate, snapshotted per instance (api.fit caches
         # one Copml per (workload, gate), so flipping it between fits
         # builds a new driver instead of reusing the old schedule)
@@ -206,7 +222,9 @@ class Copml:
         caller can dispatch the training loop at once.
         """
         self.pad = 0
-        with spans.span("setup", m=self.m, d=self.d, n=self.cfg.n_clients):
+        n, mk = self.cfg.n_clients, -(-self.m // self.cfg.k)
+        with spans.span("setup", m=self.m, d=self.d, n=n,
+                        chunks=setup_chunks(n, mk, self.d)):
             x = np.concatenate([np.asarray(x) for x in client_xs], axis=0)
             # the objective owns the target embedding (binary {0,1} passes
             # through; multiclass one-hots integer labels into (m, C))
@@ -216,65 +234,118 @@ class Copml:
                                   jnp.asarray(x),
                                   jnp.asarray(targets, jnp.float32))
 
-    def _setup_phases(self, key, x, targets) -> CopmlState:
+    def _setup_phases(self, key, x, targets, chunks=None) -> CopmlState:
         """The field work of `setup` on the stacked rows x (m, d) and
         targets (m,) + out_shape: the body of `_setup_program`.
 
-        Fully batched: every phase is one vectorized field op -- no
-        per-client Python loop.  Sharing the stacked rows in a single
-        shamir.share call is distribution-identical to per-client sharing
-        (the masking polynomial draws independent randomness per element
-        either way) and collapses N share matmuls into one.  It also gives
-        X and y sharing independent keys.
+        Every phase is one vectorized field op over all N clients -- no
+        per-client Python loop.  Sharing the stacked rows at once is
+        distribution-identical to per-client sharing (the masking
+        polynomial draws independent randomness per element either way).
+
+        LCC encoding maps row j of every block to row j of X~, so Phases
+        2a-2d stream over `chunks` chunks of the row index within a block
+        (`setup_chunks` when None): each chunk shares its rows of X, encodes
+        and reconstructs them into X~, and adds its local X^T y products;
+        the degree reduction runs once after the loop.  Every random tensor
+        is drawn whole and sliced per chunk, and the field arithmetic is
+        exact, so any chunking gives the same bits.
         """
-        cfg, n = self.cfg, self.cfg.n_clients
+        cfg, n, t, k = self.cfg, self.cfg.n_clients, self.cfg.t, self.cfg.k
         keys = jax.random.split(key, 6)
+        mk = -(-self.m // k)
+        chunks = setup_chunks(n, mk, self.d) if chunks is None else chunks
+        rows = -(-mk // chunks)
+
+        def whole_chunks(a, axis):
+            """`a` zero-padded on its row-within-block axis from mk rows to
+            rows * chunks."""
+            widths = [(0, 0)] * a.ndim
+            widths[axis] = (0, rows * chunks - mk)
+            return jnp.pad(a, widths)
+
+        def blocks(a, axis):
+            """The K row blocks of `a` (rows on `axis`) in whole chunks:
+            (K, rows * chunks) on axis, axis + 1."""
+            return whole_chunks(lagrange.partition_rows(a, k, axis)[0],
+                                axis + 1)
 
         # Phase 1 (LOCAL): quantize into F_p -- one call over all rows
         xq = quantize.quantize(x, cfg.lx)                         # (m, d)
         yq = quantize.quantize(targets, cfg.lg)         # (m,) + out_shape
 
-        # Phase 2a (EXCHANGE): Shamir-share every client's data (batched)
+        # Phase 2a (EXCHANGE): Shamir-share every client's data; X's
+        # masking coefficients are drawn here and applied per chunk
         with jax.named_scope("copml.setup.share"):
-            x_shares = shamir.share(keys[0], xq, cfg.t, n, self.lambdas)
-            y_shares = shamir.share(keys[1], yq, cfg.t, n, self.lambdas)
-        # (N, m, d) / (N, m) + out_shape
+            x_blocks = blocks(xq, 0)                        # (K, mk', d)
+            x_coeffs = blocks(field.random_field(
+                keys[0], (t, self.m, self.d)), 1)        # (T, K, mk', d)
+            y_shares = shamir.share(keys[1], yq, t, n, self.lambdas)
+            y_mat = y_shares if self.out_shape else y_shares[..., None]
+            y_blocks = blocks(y_mat, 1)                 # (N, K, mk', C')
 
         with jax.named_scope("copml.setup.encode"):
-            # Phase 2b (LOCAL on shares): partition rows into K blocks
-            blocks = jax.vmap(lambda s: lagrange.partition_rows(
-                s, cfg.k)[0])(x_shares)                   # (N, K, mk, d)
-
             # shared random masks Z_{K+1..K+T} (offline randomness, fn. 3)
-            z = field.random_field(keys[2], (cfg.t, blocks.shape[2], self.d))
-            z_shares = shamir.share(keys[3], z, cfg.t, n, self.lambdas)
-            # (N, T, mk, d)
+            z = field.random_field(keys[2], (t, mk, self.d))
+            z_coeffs = field.random_field(keys[3], (t,) + z.shape)
+            z = whole_chunks(z, 1)                          # (T, mk', d)
+            z_coeffs = whole_chunks(z_coeffs, 2)          # (T, T, mk', d)
 
-            # Phase 2c (LOCAL): LCC-encode the shares; (EXCHANGE):
-            # reconstruct each client's coded slice from T+1 shares
-            # (fn. 4: subgrouping)
-            enc = jax.vmap(lambda b, zz: lagrange.lcc_encode(
-                b, zz, self.alphas, self.betas))(blocks, z_shares)
-            # enc: (N_holder, N_owner, mk, d); reconstruct over holders
-            coded_x = shamir.reconstruct(enc, cfg.t,
-                                         self.lambdas)        # (N, mk, d)
+        def chunk(j):
+            """(X~ rows, X^T y local products) of rows j*rows.. of every
+            block."""
+            def rows_of(a, axis):
+                return jax.lax.dynamic_slice_in_dim(a, j * rows, rows, axis)
 
-        # Phase 2d: X^T y via one secure matmul (degree reduction
-        # included); a matrix objective contracts against all C target
-        # columns at once
+            with jax.named_scope("copml.setup.share"):
+                x_shares = shamir.share_with(
+                    rows_of(x_coeffs, 2), rows_of(x_blocks, 1), n,
+                    self.lambdas)                         # (N, K, rows, d)
+            with jax.named_scope("copml.setup.encode"):
+                z_shares = shamir.share_with(
+                    rows_of(z_coeffs, 2), rows_of(z, 1), n,
+                    self.lambdas)                         # (N, T, rows, d)
+                # Phase 2c (LOCAL): LCC-encode the shares; (EXCHANGE):
+                # reconstruct each client's coded slice from T+1 shares
+                # (fn. 4: subgrouping)
+                enc = jax.vmap(lambda b, zz: lagrange.lcc_encode(
+                    b, zz, self.alphas, self.betas))(x_shares, z_shares)
+                # enc: (N_holder, N_owner, rows, d); reconstruct over holders
+                coded = shamir.reconstruct(enc, t, self.lambdas)
+            # Phase 2d: the local products of X^T y (degree 2T); a matrix
+            # objective contracts against all C target columns at once
+            with jax.named_scope("copml.setup.xty"):
+                xs = x_shares.reshape(n, k * rows, self.d)
+                ys = rows_of(y_blocks, 2).reshape(n, k * rows, -1)
+                prod = mpc.local_product(jnp.swapaxes(xs, 1, 2), ys,
+                                         matmul=True)       # (N, d, C')
+            return coded, prod
+
+        if chunks == 1:
+            coded_x, prod = chunk(0)
+        else:
+            def body(j, acc):
+                coded_x, prod = acc
+                coded, part = chunk(j)
+                return (jax.lax.dynamic_update_slice_in_dim(
+                    coded_x, coded, j * rows, 1), field.add(prod, part))
+            coded_x, prod = jax.lax.fori_loop(0, chunks, body, (
+                jnp.zeros((n, rows * chunks, self.d), field.FIELD_DTYPE),
+                jnp.zeros((n, self.d, y_blocks.shape[-1]),
+                          field.FIELD_DTYPE)))
+            coded_x = coded_x[:, :mk]                       # (N, mk, d)
+
+        # Phase 2d: X^T y via one secure matmul -- the degree reduction of
+        # the summed local products
         with jax.named_scope("copml.setup.xty"):
-            y_mat = y_shares if self.out_shape else y_shares[..., None]
-            xty_shares = self._mul(
-                keys[4],
-                jnp.swapaxes(x_shares, 1, 2), y_mat,
-                cfg.t, matmul=True, points=self.lambdas)     # (N, d, C')
+            xty_shares = self._reduce(keys[4], prod, t, self.lambdas)
         if not self.out_shape:
             xty_shares = xty_shares[..., 0]                  # (N,) + w_shape
 
         # model init within MPC: w^(0) = 0 shared
         w_shares = shamir.share(
             keys[5], jnp.zeros(self.w_shape, field.FIELD_DTYPE),
-            cfg.t, n, self.lambdas)
+            t, n, self.lambdas)
         return CopmlState(w_shares=w_shares, coded_x=coded_x,
                           xty_shares=xty_shares,
                           step=jnp.asarray(0, jnp.int32))
@@ -970,18 +1041,20 @@ def _pad_clients(arr, n_pad: int):
     return jnp.concatenate([arr, pad], axis=0)
 
 
-@partial(jax.jit, static_argnums=(0, 1, 2, 3))
+@partial(jax.jit, static_argnums=(0, 1, 2, 3), static_argnames="_chunks")
 def _setup_program(cfg: CopmlConfig, objective, m: int, d: int, key, x,
-                   targets) -> CopmlState:
+                   targets, _chunks=None) -> CopmlState:
     """Copml.setup's field work (Phases 1-2) as one XLA program.
 
     The static arguments are the values that define the program, not a
     Copml instance, so every instance of one workload shares the
     executable.  Its phases are named by the device scopes
     `copml.setup.share`, `copml.setup.encode` and `copml.setup.xty`.
-    Under `jax.disable_jit()` the same body runs op by op.
+    Under `jax.disable_jit()` the same body runs op by op.  `_chunks`
+    overrides `setup_chunks` for tests of the streamed encode.
     """
-    return Copml(cfg, m, d, objective)._setup_phases(key, x, targets)
+    return Copml(cfg, m, d, objective)._setup_phases(key, x, targets,
+                                                     _chunks)
 
 
 @partial(jax.jit, static_argnames=("proto", "iters", "subset", "history"))

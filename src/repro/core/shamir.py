@@ -61,7 +61,16 @@ def share(key, secret, t: int, n: int,
     if t == 0:
         return jnp.broadcast_to(secret[None], (n,) + secret.shape)
     coeffs = field.random_field(key, (t,) + secret.shape)  # R_1..R_T
-    pmat = jnp.asarray(_power_matrix(points, t))            # (N, T)
+    return share_with(coeffs, secret, n, points)
+
+
+def share_with(coeffs, secret, n: int, points: Sequence[int]) -> Share:
+    """The N shares of `secret` under the given masking coefficients
+    R_1..R_T, coeffs (T, *secret.shape): what `share` computes after its
+    draw.  A slice of `share`'s draw shares the matching slice of the
+    secret, so a caller can draw whole and share piece by piece."""
+    t = coeffs.shape[0]
+    pmat = jnp.asarray(_power_matrix(tuple(points), t))     # (N, T)
     mix = field.matmul(pmat, coeffs.reshape(t, -1))         # (N, numel)
     return field.add(mix.reshape((n,) + secret.shape), secret[None])
 

@@ -3,8 +3,10 @@ profiler's trace, device scopes in the compiled setup's and loop's op
 metadata, and nothing recorded outside a profiler session."""
 
 import glob
+import re
 
 import jax
+import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
@@ -57,7 +59,7 @@ def test_every_span_nests_in_its_fit(traced_fits):
         assert by_name["repro:loop"][2] == {"fit": fit_id, "iters": 2}
         setup = by_name["repro:setup"]
         assert setup[2] == {"fit": fit_id, "m": wl.m, "d": wl.d,
-                            "n": wl.n_clients}
+                            "n": wl.n_clients, "chunks": 1}
         assert setup[1] <= by_name["repro:loop"][0]
 
 
@@ -82,6 +84,31 @@ def test_the_setup_program_names_its_scopes():
     for scope in SETUP_SCOPES:
         assert f"/{scope}/" in hlo, scope
     assert not any(f"/{scope}/" in hlo for scope in LOOP_SCOPES)
+
+
+@pytest.mark.parametrize("m,rows,chunks", [(64, 16, 1), (60, 5, 3)])
+def test_the_setup_span_counts_the_chunks_the_program_streams(
+        monkeypatch, tmp_path, m, rows, chunks):
+    """`repro:setup`'s `chunks` is the trip count of the setup program's
+    encode loop (no loop for one chunk), at shapes no other test compiles
+    and an encode budget of `rows` rows of a block's m/K."""
+    cfg = api.get_workload("smoke").cfg                  # N = 13, K = 4
+    d = 10
+    monkeypatch.setattr(protocol, "SETUP_ENCODE_CHUNK_BYTES",
+                        rows * cfg.n_clients ** 2 * d * 4)
+    rng = np.random.default_rng(chunks)
+    x = rng.uniform(-1, 1, (m, d)).astype(np.float32)
+    y = (rng.uniform(size=m) > 0.5).astype(np.float32)
+    proto = protocol.Copml(cfg, m, d)
+    with jax.profiler.trace(str(tmp_path)):
+        proto.setup(jax.random.PRNGKey(0), [x], [y])
+    (args,) = [a for n, _, _, a in host_spans(tmp_path)
+               if n == "repro:setup"]
+    jaxpr = str(protocol._setup_program.trace(
+        cfg, proto.obj, m, d, jax.random.PRNGKey(0), x, y).jaxpr)
+    loops = re.findall(r"scan\[|length=(\d+)", jaxpr)
+    assert args["chunks"] == chunks
+    assert [int(n) for n in loops if n] == ([] if chunks == 1 else [chunks])
 
 
 def test_a_span_outside_a_session_records_nothing(tmp_path):

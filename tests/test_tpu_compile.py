@@ -1,4 +1,5 @@
-"""Compile rehearsal: the main-path Pallas kernels compile for a TPU v5e.
+"""Compile rehearsal: the main-path Pallas kernels, and the setup and loop
+programs at the paper's full CIFAR-10 size, compile for a TPU v5e.
 
 Interpret mode (what the other kernel tests run on the CPU) accepts code
 that Mosaic refuses -- unaligned blocks, vector loads of per-client
@@ -6,7 +7,9 @@ scalars, more VMEM than a kernel may use.  These tests compile each kernel
 for a described v5e chip (no chip attached) at the shapes the chip smoke
 run and the registered workloads use, with interpret mode off, and check
 that the compiled program holds the Mosaic kernel (`tpu_custom_call`).
-Nothing runs, so they say nothing about results or times.
+The whole-program test checks the compiler's memory analysis against one
+chip's memory instead.  Nothing runs, so they say nothing about results
+or times.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -109,3 +112,40 @@ def test_poly_eval_compiles(one_chip):
         lambda z, cf: ops.poly_eval(z, cf, force_pallas=True),
         one_chip, (50, 3073), (2,))
     assert "tpu_custom_call" in txt
+
+
+# The paper's CIFAR-10 Case 2 at full m on one 16 GB v5e.  Setup's compiled
+# temporaries and the X~ it leaves behind take at most SETUP_BUDGET; the
+# loop program, which runs after setup's temporaries are freed, holds X~
+# among its arguments and outputs and must fit in what remains.
+CHIP_BYTES = 16 * 2**30
+SETUP_BUDGET = 8 * 2**30
+
+
+def test_full_size_case2_setup_and_loop_fit_one_chip(one_chip):
+    from repro.core import objectives, protocol
+    n, k, t, m, d = 50, 10, 7, 9019, 3073
+    cfg = protocol.CopmlConfig(n_clients=n, k=k, t=t)
+    mk = -(-m // k)
+    assert protocol.setup_chunks(n, mk, d) > 1
+
+    def shape(s, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    key = shape((2,), jnp.uint32)
+    setup = protocol._setup_program.lower(
+        cfg, objectives.BINARY_LOGISTIC, m, d, key,
+        shape((m, d), jnp.float32), shape((m,), jnp.float32)).compile()
+    xtilde = n * mk * d * 4
+    temp = setup.memory_analysis().temp_size_in_bytes
+    assert temp + xtilde <= SETUP_BUDGET, (temp, xtilde)
+
+    state = protocol.CopmlState(
+        w_shares=shape((n, d)), coded_x=shape((n, mk, d)),
+        xty_shares=shape((n, d)), step=shape(()))
+    loop = protocol._scan_iterations.lower(
+        protocol.Copml(cfg, m, d), key, state, 50, None, False,
+        None).compile().memory_analysis()
+    held = loop.temp_size_in_bytes + loop.argument_size_in_bytes + \
+        loop.output_size_in_bytes
+    assert held <= CHIP_BYTES - SETUP_BUDGET, held

@@ -248,15 +248,29 @@ def _recombine_limb_products(s):
     return recombine_limb_groups(groups)
 
 
+def _tpu_backend() -> bool:
+    return jax.default_backend() == "tpu"
+
+
 def matmul(a, b):
     """(a @ b) mod p for int32 field matrices a:(M,K), b:(K,N).
 
     TPU-native: 16 exact f32 matmuls per <=1024-wide K-chunk + int32 modular
     recombination.  No intermediate exceeds f32's exact-int range or int32.
+
+    On a TPU a short contraction with a wide output (the shapes that
+    kernels/short_modmatmul.routes admits: LCC encode, Shamir share and
+    reconstruct) runs as one Pallas pass that keeps the limb products in
+    VMEM, under the scope `field.short_matmul`; it gives the same bits.
     """
     m, k = a.shape
     k2, n = b.shape
     assert k == k2, (a.shape, b.shape)
+    if _tpu_backend():
+        from ..kernels import short_modmatmul   # kernels import this module
+        if short_modmatmul.routes(m, k, n):
+            with jax.named_scope("field.short_matmul"):
+                return short_modmatmul.short_modmatmul(a, b)
     out = jnp.zeros((m, n), dtype=jnp.int32)
     for start in range(0, k, MATMUL_CHUNK):
         stop = min(start + MATMUL_CHUNK, k)

@@ -134,9 +134,11 @@ def case2_params(n: int, r: int = 1) -> tuple:
 
 # Setup streams its LCC encode over chunks of the row index within a block.
 # One chunk's encode tensor, (N holders, N owners, rows, d) int32, may take
-# at most this many bytes; the limb products of its field matmul take 16
-# times as much in f32 (4 GiB), which with setup's whole-size random draws
-# and X~ leaves the training loop room on a 16 GB TPU v5e.
+# at most this many bytes.  On a TPU its field products keep their limb
+# products in VMEM (kernels/short_modmatmul), so a chunk holds little more
+# than this tensor and its inputs; on other backends the limb products take
+# 16 times as much in f32 (4 GiB).  With setup's whole-size random draws
+# and X~ this leaves the training loop room on a 16 GB TPU v5e.
 SETUP_ENCODE_CHUNK_BYTES = 1 << 28
 
 

@@ -15,6 +15,7 @@ from repro.kernels import coded_gradient as cgk
 from repro.kernels import field_poly as fpk
 from repro.kernels import modmatmul as mmk
 from repro.kernels import ops, ref
+from repro.kernels import short_modmatmul as smm
 
 
 @pytest.mark.parametrize("m,k,n", [
@@ -119,6 +120,35 @@ def test_modmatmul_batched_matches_vmap(rng, bsz, m, k, n):
         lambda ai, bi: ops.modmatmul(ai, bi, force_pallas=True,
                                      bm=16, bn=16, bk=32))(a, b))
     np.testing.assert_array_equal(vmapped, expected)
+
+
+@pytest.mark.parametrize("m", [1, 8, 50])
+@pytest.mark.parametrize("k", [1, 7, 8, 17, 50])
+def test_short_modmatmul_matches_field_matmul(rng, m, k):
+    """The short-contraction kernel equals field.matmul's jnp path and the
+    uint64 oracle: N = 3073 is no multiple of 128 (an edge block; two grid
+    steps at M = K = 50), random and all-(p-1) operands, plain and under
+    vmap with `a` unbatched (the lcc_encode pattern), where it stays one
+    pallas_call."""
+    n = 3073
+
+    def kernel(a, b):
+        return smm.short_modmatmul(a, b, interpret=True)
+
+    a = jnp.asarray(rng.integers(0, F.P, size=(m, k)).astype(np.int32))
+    bs = jnp.asarray(rng.integers(0, F.P, size=(2, k, n)).astype(np.int32))
+    cases = [(a, bs[0]), (jnp.full((m, k), F.P - 1, jnp.int32),
+                          jnp.full((k, n), F.P - 1, jnp.int32))]
+    for x, y in cases:
+        expected = F.np_matmul(np.asarray(x), np.asarray(y))
+        np.testing.assert_array_equal(np.asarray(kernel(x, y)), expected)
+        np.testing.assert_array_equal(np.asarray(F.matmul(x, y)), expected)
+    batched = jax.vmap(kernel, in_axes=(None, 0))
+    assert str(jax.make_jaxpr(batched)(a, bs)).count("pallas_call") == 1
+    got = np.asarray(batched(a, bs))
+    for i in range(2):
+        np.testing.assert_array_equal(
+            got[i], F.np_matmul(np.asarray(a), np.asarray(bs[i])))
 
 
 def test_matvec_batched_extreme(rng):

@@ -1,5 +1,6 @@
-"""Compile rehearsal: the main-path Pallas kernels, and the setup and loop
-programs at the paper's full CIFAR-10 size, compile for a TPU v5e.
+"""Compile rehearsal: the main-path Pallas kernels, field.matmul's choice of
+kernel at the protocol's product shapes, and the setup and loop programs at
+the paper's full CIFAR-10 size, compile for a TPU v5e.
 
 Interpret mode (what the other kernel tests run on the CPU) accepts code
 that Mosaic refuses -- unaligned blocks, vector loads of per-client
@@ -20,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.core import field
 from repro.kernels import ops
 
 
@@ -41,11 +43,13 @@ def one_chip(topo):
 
 @pytest.fixture(autouse=True)
 def mosaic(monkeypatch):
-    """Compile the kernels (interpret off) although the backend is the CPU,
-    with the persistent compilation cache off: entries written for a
-    described chip cannot be read back without one."""
+    """Compile the kernels (interpret off) and let field.matmul choose as on
+    a TPU, although the backend is the CPU, with the persistent compilation
+    cache off: entries written for a described chip cannot be read back
+    without one."""
     from jax.experimental.compilation_cache import compilation_cache
     monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    monkeypatch.setattr(field, "_tpu_backend", lambda: True)
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -114,6 +118,41 @@ def test_poly_eval_compiles(one_chip):
     assert "tpu_custom_call" in txt
 
 
+# field.matmul's products on the cells' paths (per holder; Case 2 at full m
+# unless named) and whether each takes the short-contraction kernel: the
+# Mosaic call where the contraction is short and the output wide, the jnp
+# limb path elsewhere.  `batch` holders under vmap, `a` unbatched (None) or
+# batched.
+@pytest.mark.parametrize("a_shape,b_shape,batch,kernel", [
+    ((50, 17), (17, 24584), (50, None), True),    # setup lcc_encode
+    ((50, 7), (7, 245840), None, True),           # setup share_with, X
+    ((50, 7), (7, 172088), None, True),           # setup share_with, Z
+    ((50, 1), (1, 393344), None, True),           # X sharing, Case 1
+    ((1, 8), (8, 1229200), None, True),           # setup reconstruct
+    ((50, 17), (17, 3073), (50, None), True),     # loop encode_model
+    ((1, 50), (50, 153650), None, True),          # reconstruct, all holders
+    ((50, 7), (7, 153650), None, True),           # step_rand share of zeros
+    ((1, 50), (50, 3073), (50, None), True),      # step_rand base
+    ((50, 7), (7, 3073), None, True),             # TruncPr shares
+    ((3073, 80), (80, 1), (50, 0), False),        # setup X^T y local product
+    ((902, 3073), (3073, 1), (50, 0), False),     # ref.fused_step, X~ w
+    ((3073, 902), (902, 1), (50, 0), False),      # ref.fused_step, X~^T g
+], ids=["lcc_encode", "share_x", "share_z", "share_x_case1", "reconstruct",
+        "encode_model", "reconstruct_all", "mix", "base", "trunc_pr",
+        "xty_local_product", "fused_step_xw", "fused_step_xtg"])
+def test_field_matmul_chooses_by_shape(one_chip, a_shape, b_shape, batch,
+                                       kernel):
+    # a fresh function: no trace cached under the CPU's choice is reused
+    fn, shapes = (lambda a, b: field.matmul(a, b)), [a_shape, b_shape]
+    if batch is not None:
+        size, a_axis = batch
+        fn = jax.vmap(fn, in_axes=(a_axis, 0))
+        shapes = [a_shape if a_axis is None else (size,) + a_shape,
+                  (size,) + b_shape]
+    txt = _compile_text(fn, one_chip, *shapes)
+    assert ("tpu_custom_call" in txt) == kernel
+
+
 # The paper's CIFAR-10 Case 2 at full m on one 16 GB v5e.  Setup's compiled
 # temporaries and the X~ it leaves behind take at most SETUP_BUDGET; the
 # loop program, which runs after setup's temporaries are freed, holds X~
@@ -136,6 +175,7 @@ def test_full_size_case2_setup_and_loop_fit_one_chip(one_chip):
     setup = protocol._setup_program.lower(
         cfg, objectives.BINARY_LOGISTIC, m, d, key,
         shape((m, d), jnp.float32), shape((m,), jnp.float32)).compile()
+    assert "tpu_custom_call" in setup.as_text()    # the short products
     xtilde = n * mk * d * 4
     temp = setup.memory_analysis().temp_size_in_bytes
     assert temp + xtilde <= SETUP_BUDGET, (temp, xtilde)

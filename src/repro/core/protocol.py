@@ -39,9 +39,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from . import (field, lagrange, meshutil, mpc, objectives, quantize, shamir,
-               spans, truncation)
-from .labels import Coded, Opened, Public, Share
+from . import (field, lagrange, meshutil, mpc, objectives, party, quantize,
+               shamir, spans, truncation)
+from .labels import Coded, Opened, Share
 
 
 @dataclasses.dataclass(frozen=True)
@@ -357,35 +357,22 @@ class Copml:
     def encode_model(self, key, w_shares: Share) -> Coded:
         """Phase 2 per-iteration: Lagrange-encode w from its shares.
 
-        LOCAL on shares + EXCHANGE to reconstruct w~_j at client j.
-        v(beta_k) = w for all k in [K]; T random vectors v_k pad the tail.
+        LOCAL on shares + EXCHANGE to reconstruct w~_j at client j: one
+        party block of all N holders (core/party.py), whose reconstruct
+        partial is the whole coded model.
         """
         with jax.named_scope("copml.encode_model"):
-            cfg, n = self.cfg, self.cfg.n_clients
-            kv, ks = jax.random.split(key)
-            # distinct keys: drawing v and its sharing polynomial from the
-            # same key makes the sharing coefficients EQUAL v (same
-            # threefry stream), letting any single share reveal the mask
-            v = field.random_field(kv, (cfg.t,) + self.w_shape)
-            v_shares = shamir.share(ks, v, cfg.t, n,
-                                    self.lambdas)       # (N,T)+w_shape
-            # LCC encoding is elementwise-linear: flatten the trailing model
-            # dims so vector and matrix models share one encode path (dw = d
-            # for the vector objectives -- these reshapes are no-ops there)
-            w_flat = w_shares.reshape(n, self.dw)
-            v_flat = v_shares.reshape(n, cfg.t, self.dw)
-            blocks = jnp.broadcast_to(
-                w_flat[:, None], (n, cfg.k, self.dw))   # same w in K slots
-            enc = jax.vmap(lambda b, vv: lagrange.lcc_encode(
-                b[:, None, :], vv[:, None, :], self.alphas, self.betas
-            )[:, 0, :])(blocks, v_flat)                 # (N_holder,N_owner,dw)
+            n = self.cfg.n_clients
+            pmat, wall = party.padded_constants(self, n)
+            enc = party.encode_model(self, key, w_shares, jnp.asarray(pmat),
+                                     n)                 # (N_holder,N_owner,dw)
             # keep enc holder-sharded: otherwise GSPMD all-gathers every
             # holder's (K+T, d) limb stack (~1 GiB/step at N=256, the
             # dominant collective of the baseline -- EXPERIMENTS.md Perf,
             # COPML iter 2); reconstruct from ALL N shares so the
             # contraction reduce-scatters.
             enc = meshutil.maybe_constrain(enc, meshutil.CLIENTS)
-            out = shamir.reconstruct(enc, cfg.t, self.lambdas, subset="all")
+            out = party.model_partial(enc, jnp.asarray(wall))
             return meshutil.maybe_constrain(out, meshutil.CLIENTS)  # (N, d)
 
     def local_gradient(self, coded_x: Coded, coded_w: Coded) -> Coded:
@@ -414,7 +401,8 @@ class Copml:
     def decode_and_update(self, key, state: CopmlState, f_values: Coded,
                           subset: Sequence[int] | None = None, *,
                           subset_idx=None, dvec=None) -> CopmlState:
-        """Phase 4: share f, decode on shares, secure model update.
+        """Phase 4: share f, decode on shares, secure model update, as one
+        party block of all N clients (core/party.py).
 
         The decode subset comes in one of two forms: a static `subset`
         tuple (host constant, the pre-fault-plan path), or traced
@@ -430,48 +418,25 @@ class Copml:
                 subset = tuple(range(rthr))
             subset = tuple(subset)[:rthr]
             subset_idx = jnp.asarray(subset)
-            dvec = jnp.asarray(self._decode_vec(subset))         # (R,)
+            dvec = jnp.asarray(party.decode_row(self, subset))   # (R,)
         else:
             assert dvec is not None, "subset_idx needs its decode row dvec"
+        pmat = jnp.asarray(party.padded_constants(self, n)[0])
 
-        # EXCHANGE: each client shares its local result
-        f_shares = shamir.share_batch(kf, f_values, cfg.t, n,
-                                      self.lambdas)  # (N_owner, N_holder, d)
-
-        # EXCHANGE: transpose owner<->holder (all-to-all on the mesh), then
-        # decode LOCALLY per holder.  Decoding before the transpose makes
-        # GSPMD all-reduce a (K, N, d) tensor -- ~K x more wire bytes than
-        # the (N, d) exchange the protocol actually needs (EXPERIMENTS.md
-        # section Perf, COPML cell, iteration 1).
+        # EXCHANGE: each client shares its local result with every holder
+        # (the deal is holder-major), then each holder decodes LOCALLY.
+        # Decoding before the owner->holder exchange makes GSPMD all-reduce
+        # a (K, N, d) tensor -- ~K x more wire bytes than the (N, d)
+        # exchange the protocol actually needs (EXPERIMENTS.md section
+        # Perf, COPML cell, iteration 1).
         per_holder = meshutil.maybe_constrain(
-            jnp.swapaxes(f_shares, 0, 1), meshutil.CLIENTS)
-        # (N_holder, N_owner) + w_shape; each holder decodes from its R
-        # rows.  sum over K commutes with the decode matmul: fold it into
-        # ONE matvec row  (sum_k D[k, :]) @ evals  -- K x less local work.
-        # Trailing model dims flatten into the element axis (no-op for
-        # vector objectives).
-        evals = per_holder[:, subset_idx]                  # (N_h, R)+w_shape
-        evals = evals.reshape(n, evals.shape[1], self.dw)
-        xtg_shares = jax.vmap(
-            lambda e: field.matmul(dvec[None], e)[0])(evals)
-        xtg_shares = xtg_shares.reshape((n,) + self.w_shape)
-
-        # LOCAL: gradient shares; then secure update with TruncPr
-        grad_shares = field.sub(xtg_shares, state.xty_shares)
-        scaled = field.mul_scalar(grad_shares, self.q_eta)
-        delta_shares = truncation.trunc_pr(
-            kt, scaled, self.k1, self.k2, cfg.t, self.lambdas)   # scale lw
-        new_w = field.sub(state.w_shares, delta_shares)
+            party.deal(self, kf, f_values, pmat, 0)(),
+            meshutil.CLIENTS)                      # (N_holder, N_owner, dw)
+        new_w = party.update(
+            self, kt, per_holder[:, subset_idx], dvec, state.w_shares,
+            state.xty_shares, pmat,
+            open_=lambda c_sh: shamir.reconstruct(c_sh, cfg.t, self.lambdas))
         return dataclasses.replace(state, w_shares=new_w, step=state.step + 1)
-
-    def _decode_vec(self, subset) -> Public:
-        """Host-side (R,) decode row: sum_k D[k, :] over the K decode-matrix
-        rows, mod p.  Shared by the single-device and sharded engines so both
-        trace the exact same public constant."""
-        sub_alphas = [self.alphas[i] for i in subset]
-        dmat = lagrange.decode_matrix(
-            sub_alphas, self.betas[: self.cfg.k]).astype(np.int64)  # (K, R)
-        return (dmat.sum(axis=0) % field.P).astype(np.int32)
 
     def _fused_iteration(self, key, state: CopmlState, coded_w: Coded,
                          subset=None, *, subset_idx=None, dvec=None,
@@ -482,8 +447,8 @@ class Copml:
         operand handed to the kernel consumes the SAME randomness stream:
 
         * `mix` is shamir.share(kf, ZEROS) -- identical masking coefficients
-          to decode_and_update's share_batch(kf, f) (the coefficient draw
-          depends only on key and shape), so share(h, o) = mix(h, o) + f(o)
+          to party.deal's (T, N) + w_shape draw under kf (the coefficient
+          draw depends only on key and shape), so share(h, o) = mix(h, o) + f(o)
           and the holder-h decode splits into the value-independent
           base[h] = dfull @ mix[h] (computed here) plus the holder-
           independent dfull @ f_adj (computed in the kernel epilogue).
@@ -503,7 +468,7 @@ class Copml:
                 subset = tuple(range(rthr))
             subset = tuple(subset)[:rthr]
             dfull_np = np.zeros(n, np.int32)
-            dfull_np[list(subset)] = self._decode_vec(subset)
+            dfull_np[list(subset)] = party.decode_row(self, subset)
             dfull = jnp.asarray(dfull_np)
         else:
             assert dvec is not None, "subset_idx needs its decode row dvec"
@@ -603,7 +568,8 @@ class Copml:
         into the (iters, R) gather-index and decode-row arrays the engines
         consume (exact-integer Lagrange rows, one per distinct subset)."""
         return shamir.step_subset_arrays(
-            step_subsets, self.cfg.recovery_threshold, self._decode_vec)
+            step_subsets, self.cfg.recovery_threshold,
+            partial(party.decode_row, self))
 
     def _fault_xs(self, step_subsets, adversaries, iters: int, subset=None):
         """(idx, dvec, adv-or-None) scan inputs for a faulty run, or None."""
@@ -855,84 +821,34 @@ class Copml:
         ndev = mesh.shape[meshutil.CLIENT_AXIS]
         n_loc = -(-n // ndev)
         n_pad = n_loc * ndev
-        t_, kk = cfg.t, cfg.k
+        t_ = cfg.t
         axis = meshutil.CLIENT_AXIS
 
-        # public per-client constants, zero-padded so padded clients carry
-        # zero Lagrange weight and a zero sharing polynomial
-        pmat = np.zeros((n_pad, t_), np.int32)
-        pmat[:n] = shamir._power_matrix(tuple(self.lambdas), t_)
-        wall = np.zeros((n_pad,), np.int32)
-        wall[:n] = shamir._recon_matrix(tuple(self.lambdas))[0]
+        pmat, wall = party.padded_constants(self, n_pad)
         sub = tuple(range(cfg.recovery_threshold)) if subset is None \
             else tuple(subset)[: cfg.recovery_threshold]
-        dvec = jnp.asarray(self._decode_vec(sub))                # (R,)
+        dvec = jnp.asarray(party.decode_row(self, sub))          # (R,)
         sub_arr = jnp.asarray(sub)
 
-        def share_rows(keyc, secret, pmat_loc):
-            """This shard's holder rows of shamir.share(keyc, secret, t, n):
-            the coefficient draw is replicated (same key on every shard --
-            the offline dealer), only the public power-matrix rows are
-            shard-local, so per-row values match the global share bits."""
-            coeffs = field.random_field(keyc, (t_,) + secret.shape)
-            mix = field.matmul(pmat_loc, coeffs.reshape(t_, -1))
-            return field.add(
-                mix.reshape((pmat_loc.shape[0],) + secret.shape), secret[None])
+        def open_(c_sh):
+            """TruncPr's masked value, OPENed via all_gather."""
+            c_full = meshutil.all_gather_clients(c_sh, axis)[:n]
+            return shamir.reconstruct(c_full, t_, self.lambdas)
 
         def encode_model(k1_, w_loc, pmat_loc, wall_loc):
-            """Phase-2 per-iteration model encoding, holder-sharded.
-
-            Randomness shapes mirror the unsharded engine exactly ((T,) +
-            w_shape draws, replicated dealer), so the engines stay
-            bit-exact for every objective; the trailing model dims flatten
-            to dw for the encode matmuls as in Copml.encode_model."""
-            kv, ks_ = jax.random.split(k1_)
-            v = field.random_field(kv, (t_,) + w_shape)
-            v_sh = share_rows(ks_, v, pmat_loc)            # (n_loc,T)+w_shape
-            n_loc_ = w_loc.shape[0]
-            w_flat = w_loc.reshape(n_loc_, dw)
-            v_flat = v_sh.reshape(n_loc_, t_, dw)
-            blocks = jnp.broadcast_to(w_flat[:, None], (n_loc_, kk, dw))
-            enc = jax.vmap(lambda b, vv: lagrange.lcc_encode(
-                b[:, None, :], vv[:, None, :], self.alphas, self.betas
-            )[:, 0, :])(blocks, v_flat)                          # (n_loc,N,dw)
-            # EXCHANGE: reconstruct from ALL holders -- local weighted
-            # partial, then a mod-p reduce-scatter hands each shard its own
-            # clients' coded model rows
+            """Phase-2 per-iteration model encoding, holder-sharded.  The
+            reconstruct from ALL holders is this shard's weighted partial
+            and a mod-p reduce-scatter that hands each shard its own
+            clients' coded model rows."""
+            enc = party.encode_model(self, k1_, w_loc, pmat_loc, n_pad)
             if overlap and ndev <= meshutil.NARROW_SHARDS:
-                if n_pad > n:
-                    enc = jnp.concatenate(
-                        [enc, jnp.zeros((enc.shape[0], n_pad - n, dw),
-                                        jnp.int32)], axis=1)
-
-                def seg(j):
-                    # dest shard j's rows of the weighted partial, computed
-                    # just before hop j so the GEMM rides the transfer
-                    sl = jax.lax.dynamic_slice_in_dim(
-                        enc, j * n_loc, n_loc, axis=1)
-                    return field.matmul(
-                        wall_loc[None, :],
-                        sl.reshape(sl.shape[0], -1)).reshape(n_loc, dw)
-
-                return meshutil.ring_reduce_scatter_mod(seg, axis, ndev)
-            part = field.matmul(wall_loc[None, :],
-                                enc.reshape(enc.shape[0], -1)).reshape(n, dw)
-            if n_pad > n:
-                part = jnp.concatenate(
-                    [part, jnp.zeros((n_pad - n, dw), jnp.int32)], axis=0)
-            return meshutil.psum_scatter_mod(part, axis, ndev)   # (n_loc, dw)
-
-        def trunc(kt, a_loc, pmat_loc):
-            """TruncPr (truncation.trunc_pr_core) with shard-local share
-            rows and the masked value OPENed via all_gather."""
-            def open_(c_sh):
-                c_full = meshutil.all_gather_clients(c_sh, axis)[:n]
-                return shamir.reconstruct(c_full, t_, self.lambdas)
-
-            return truncation.trunc_pr_core(
-                kt, a_loc, self.k1, self.k2,
-                share=lambda kc, s: share_rows(kc, s, pmat_loc),
-                open_=open_)
+                # dest shard j's rows of the partial, computed just before
+                # hop j so the GEMM rides the transfer
+                return meshutil.ring_reduce_scatter_mod(
+                    lambda j: party.model_partial(enc, wall_loc, j),
+                    axis, ndev)
+            return meshutil.psum_scatter_mod(
+                party.model_partial(enc, wall_loc), axis, ndev)  # (n_loc,dw)
 
         def decode_update(k2_, w_loc, xty_loc, f_loc, pmat_loc, pmat_all,
                           shard_ix, sub_t, dv_t):
@@ -942,47 +858,19 @@ class Copml:
             (the closure constants on the static path, per-step scan slices
             on the fault-plan path)."""
             kf, kt = jax.random.split(k2_)
-            # EXCHANGE: share_batch.  The sharing-polynomial draw spans ALL
-            # owners (replicated dealer randomness, matching the global
-            # (T, N) + w_shape draw bit-for-bit); each shard keeps its own
-            # owners' columns and deals shares to every holder.  Trailing
-            # model dims flatten to dw for the exchange/decode matmuls.
-            coeffs = field.random_field(kf, (t_, n) + w_shape)
-            coeffs = coeffs.reshape(t_, n, dw)
-            if n_pad > n:
-                coeffs = jnp.concatenate(
-                    [coeffs, jnp.zeros((t_, n_pad - n, dw), jnp.int32)],
-                    axis=1)
-            cl = jax.lax.dynamic_slice_in_dim(
-                coeffs, shard_ix * n_loc, n_loc, axis=1)        # (T,n_loc,dw)
-            f_flat = f_loc.reshape(n_loc, dw)
+            block = party.deal(self, kf, f_loc, pmat_all, shard_ix * n_loc)
             if overlap:
-                def blk(j):
-                    # holder rows owned by shard j, built just before the
-                    # hop that carries them
-                    pj = jax.lax.dynamic_slice_in_dim(
-                        pmat_all, j * n_loc, n_loc, axis=0)
-                    mixj = field.matmul(pj, cl.reshape(t_, -1))
-                    return field.add(mixj.reshape(n_loc, n_loc, dw),
-                                     f_flat[None])
-
-                blocks = meshutil.ring_all_to_all(blk, axis, ndev)
+                # holder rows owned by shard j, built just before the hop
+                # that carries them
+                blocks = meshutil.ring_all_to_all(block, axis, ndev)
                 # (src, n_loc_holder, n_loc_own, dw) -> owner-major concat
                 per_holder = jnp.moveaxis(blocks, 0, 1).reshape(
                     n_loc, n_pad, dw)
             else:
-                mix = field.matmul(pmat_all, cl.reshape(t_, -1))
-                mine = field.add(mix.reshape(n_pad, n_loc, dw),
-                                 f_flat[None])    # (N_holder, n_loc_own, dw)
-                per_holder = meshutil.all_to_all_clients(mine, axis)
+                per_holder = meshutil.all_to_all_clients(block(), axis)
             # (n_loc_holder, N_owner, dw): decode LOCALLY per holder
-            evals = per_holder[:, sub_t, :]                     # (n_loc,R,dw)
-            xtg = jax.vmap(
-                lambda e: field.matmul(dv_t[None], e)[0])(evals)
-            grad = field.sub(xtg.reshape((n_loc,) + w_shape), xty_loc)
-            scaled = field.mul_scalar(grad, self.q_eta)
-            delta = trunc(kt, scaled, pmat_loc)
-            return field.sub(w_loc, delta)
+            return party.update(self, kt, per_holder[:, sub_t, :], dv_t,
+                                w_loc, xty_loc, pmat_loc, open_)
 
         def open_w(w_loc):
             w_full = meshutil.all_gather_clients(w_loc, axis)[:n]
@@ -1022,7 +910,8 @@ class Copml:
         cl = P(axis)
         out_specs = (cl, P()) if history else cl
         sm = jax.shard_map(loop, mesh=mesh,
-                           in_specs=(cl, cl, cl, cl, cl, P()) + (P(),) * n_fx,
+                           in_specs=(cl, cl, cl, cl, P(None, axis), P())
+                           + (P(),) * n_fx,
                            out_specs=out_specs, check_vma=False)
         jfn = jax.jit(sm)
         pmat_j, wall_j = jnp.asarray(pmat), jnp.asarray(wall)

@@ -52,11 +52,12 @@ def trunc_pr_core(key, a_shares: Share, k1: int, k2: int,
     """TruncPr's arithmetic, parameterized over the share/open primitives.
 
     `share(key, secret)` deals Shamir shares of the offline randomness and
-    `open_(c_shares)` publicly reconstructs the masked value.  The
-    single-device path (trunc_pr below) passes the plain shamir ops; the
-    mesh-sharded engine (protocol.Copml._sharded_scan) passes its local-row
-    share and all_gather-backed open -- ONE source of truth for the bias /
-    mask / borrow-fold math, so the two engines cannot drift.
+    `open_(c_shares)` publicly reconstructs the masked value.  trunc_pr
+    below passes the plain shamir ops; the COPML step (party.update)
+    passes its block's holder rows (party.share_rows) and the engine's
+    open: a reconstruct on one device, an all_gather on the mesh, the
+    coordinator's round in the proc engine -- ONE source of truth for the
+    bias / mask / borrow-fold math, so the engines cannot drift.
 
     a_shares: (N_local_or_global, ...) shares.  Returns shares of
     floor(a/2^{k1}) + Bernoulli((a mod 2^{k1})/2^{k1}).

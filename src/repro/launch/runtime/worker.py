@@ -1,9 +1,10 @@
 """Worker process: one COPML client group's compute + socket collectives.
 
 Each worker owns `n_loc = ceil(N / P)` consecutive clients and runs the
-exact per-step math of the mesh-sharded engine
-(core/protocol.Copml._sharded_scan) with every mesh collective replaced
-by its socket equivalent:
+per-party step of core/party.py on them, as the mesh-sharded engine
+(core/protocol.Copml._sharded_scan) does on a shard; this module holds
+only the exchange, each mesh collective replaced by its socket
+equivalent:
 
     reduce-scatter (model encode)   peer-to-peer ENC partial rows,
                                     chained field.add (exact mod-p sum)
@@ -32,9 +33,8 @@ import traceback
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from ...core import field, lagrange, shamir, truncation
+from ...core import field, party
 from ...core.protocol import Copml
 from . import net, wire
 
@@ -101,7 +101,6 @@ def _run_session(node: net.Node, sess: dict):
     n, P = cfg.n_clients, sess["n_procs"]
     n_loc = -(-n // P)
     n_pad = n_loc * P
-    t_, kk, dw, w_shape = cfg.t, cfg.k, proto.dw, proto.w_shape
     lo = rank * n_loc
     rthr = cfg.recovery_threshold
     iters, history = sess["iters"], sess["history"]
@@ -121,14 +120,10 @@ def _run_session(node: net.Node, sess: dict):
     node.recv(net.START, src=net.COORD,
               timeout=node.cfg.spawn_timeout_s, retries=1)
 
-    # public per-client constants, zero-padded exactly like _sharded_scan
-    pmat_np = np.zeros((n_pad, t_), np.int32)
-    pmat_np[:n] = shamir._power_matrix(tuple(proto.lambdas), t_)
-    wall_np = np.zeros((n_pad,), np.int32)
-    wall_np[:n] = shamir._recon_matrix(tuple(proto.lambdas))[0]
+    pmat_np, wall_np = party.padded_constants(proto, n_pad)
     pmat_all = jnp.asarray(pmat_np)
     pmat_loc = jnp.asarray(pmat_np[lo:lo + n_loc])
-    wall_loc = jnp.asarray(wall_np[lo:lo + n_loc])
+    wall_loc = jnp.asarray(wall_np[:, lo:lo + n_loc])
 
     w_loc = jnp.asarray(wire.unpack_array(sess["w_rows"]))
     coded_x = jnp.asarray(wire.unpack_array(sess["coded_rows"]))
@@ -138,13 +133,6 @@ def _run_session(node: net.Node, sess: dict):
     clock = _PhaseClock()
     dvec_cache: dict = {}
     degraded = 0
-
-    def share_rows(keyc, secret):
-        """This rank's holder rows of shamir.share(keyc, secret, t, n):
-        replicated coefficient draw, shard-local power-matrix rows."""
-        coeffs = field.random_field(keyc, (t_,) + secret.shape)
-        mix = field.matmul(pmat_loc, coeffs.reshape(t_, -1))
-        return field.add(mix.reshape((n_loc,) + secret.shape), secret[None])
 
     def open_via_coord(c_sh, step):
         """TruncPr's masked opening: gather at the coordinator, get the
@@ -170,32 +158,13 @@ def _run_session(node: net.Node, sess: dict):
         same order, same payload bits (a row slice of a matmul is the
         same contraction) -- commlint's budget holds unchanged."""
         with clock("encode"):
-            kv, ks_ = jax.random.split(k1_)
-            v = field.random_field(kv, (t_,) + w_shape)
-            v_sh = share_rows(ks_, v)
-            w_flat = w_c.reshape(n_loc, dw)
-            v_flat = v_sh.reshape(n_loc, t_, dw)
-            blocks = jnp.broadcast_to(w_flat[:, None], (n_loc, kk, dw))
-            enc = jax.vmap(lambda b, vv: lagrange.lcc_encode(
-                b[:, None, :], vv[:, None, :], proto.alphas, proto.betas
-            )[:, 0, :])(blocks, v_flat)                      # (n_loc, N, dw)
-            if n_pad > n:
-                enc = jnp.concatenate(
-                    [enc, jnp.zeros((n_loc, n_pad - n, dw), jnp.int32)],
-                    axis=1)
-
-            def seg(s):
-                sl = enc[:, s * n_loc:(s + 1) * n_loc]
-                return field.matmul(
-                    wall_loc[None, :],
-                    sl.reshape(n_loc, -1)).reshape(n_loc, dw)
-
+            enc = party.encode_model(proto, k1_, w_c, pmat_loc, n_pad)
             for s in range(P):
                 if s == rank:
                     continue
-                node.send(s, net.ENC, step=step,
-                          payload=wire.share_payload(seg(s)), phase="encode")
-            acc = seg(rank)
+                node.send(s, net.ENC, step=step, payload=wire.share_payload(
+                    party.model_partial(enc, wall_loc, s)), phase="encode")
+            acc = party.model_partial(enc, wall_loc, rank)
             for s in range(P):
                 if s == rank:
                     continue
@@ -246,22 +215,10 @@ def _run_session(node: net.Node, sess: dict):
         """Phase 4: share the coded gradients (all_to_all over sockets),
         decode locally from the arrived subset, TruncPr update."""
         kf, kt = jax.random.split(k2_)
-        # replicated global sharing-polynomial draw, own columns kept
-        coeffs = field.random_field(kf, (t_, n) + w_shape)
-        coeffs = coeffs.reshape(t_, n, dw)
-        if n_pad > n:
-            coeffs = jnp.concatenate(
-                [coeffs, jnp.zeros((t_, n_pad - n, dw), jnp.int32)], axis=1)
-        cl = coeffs[:, lo:lo + n_loc]
-        f_flat = f_loc.reshape(n_loc, dw)
-
-        def mine_block(s):
-            # holder rows owned by rank s, built just before the send so
-            # the SHARE frame for s rides the wire while s+1's block GEMM
-            # runs (same frames/order/bits as the monolithic form)
-            mixs = field.matmul(pmat_all[s * n_loc:(s + 1) * n_loc],
-                                cl.reshape(t_, -1))
-            return field.add(mixs.reshape(n_loc, n_loc, dw), f_flat[None])
+        # holder rows owned by rank s, built just before the send so the
+        # SHARE frame for s rides the wire while s+1's block GEMM runs
+        # (same frames/order/bits as the monolithic form)
+        mine_block = party.deal(proto, kf, f_loc, pmat_all, lo)
 
         with clock("exchange"):
             for s in range(P):
@@ -273,18 +230,13 @@ def _run_session(node: net.Node, sess: dict):
             blocks = {rank: mine_block(rank)}
             sub = collect_blocks(blocks, step)
         if sub not in dvec_cache:
-            dvec_cache[sub] = jnp.asarray(proto._decode_vec(sub))
-        dvt = dvec_cache[sub]
+            dvec_cache[sub] = jnp.asarray(party.decode_row(proto, sub))
         evals = jnp.stack(
             [blocks[g // n_loc][:, g - (g // n_loc) * n_loc, :]
              for g in sub], axis=1)                       # (n_loc, R, dw)
-        xtg = jax.vmap(lambda e: field.matmul(dvt[None], e)[0])(evals)
-        grad = field.sub(xtg.reshape((n_loc,) + w_shape), xty_loc)
-        scaled = field.mul_scalar(grad, proto.q_eta)
-        delta = truncation.trunc_pr_core(
-            kt, scaled, proto.k1, proto.k2, share=share_rows,
-            open_=lambda c_sh: open_via_coord(c_sh, step))
-        return field.sub(w_c, delta)
+        return party.update(proto, kt, evals, dvec_cache[sub], w_c, xty_loc,
+                            pmat_loc,
+                            open_=lambda c_sh: open_via_coord(c_sh, step))
 
     for t in range(iters):
         kit = jax.random.fold_in(key, t)

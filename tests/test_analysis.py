@@ -126,48 +126,50 @@ def test_budget_report_lists_waivers():
 
 # ---------------------------------------------------------- corruption drills
 
-def _protocol_source():
-    with open(os.path.join(SRC_REPRO, "core", "protocol.py")) as fh:
+def _core_source(name):
+    with open(os.path.join(SRC_REPRO, "core", name)) as fh:
         return fh.read()
 
 
-def _analyze_corrupted(source):
+def _analyze_corrupted(source, name):
     with tempfile.TemporaryDirectory() as tmp:
-        path = os.path.join(tmp, "protocol.py")
+        path = os.path.join(tmp, name)
         with open(path, "w") as fh:
             fh.write(source)
         return _run_cli("--package", "repro.core", path)
 
 
+# The drills corrupt core/party.py, the one home of the per-party step
+# that every engine runs: its Phase-4 update and its decode row.
 def test_corrupted_protocol_share_leak_is_flagged():
-    """Opening w_shares via print() inside decode_and_update -> SEC001."""
-    src = _protocol_source()
-    anchor = "xtg_shares = jax.vmap("
-    assert anchor in src, "protocol.py changed; update the corruption drill"
-    bad = src.replace(
-        anchor, "print(state.w_shares)\n        " + anchor, 1)
-    proc = _analyze_corrupted(bad)
+    """Opening w_rows via print() inside party.update -> SEC001."""
+    src = _core_source("party.py")
+    anchor = "xtg = jax.vmap("
+    assert anchor in src, "party.py changed; update the corruption drill"
+    bad = src.replace(anchor, "print(w_rows)\n    " + anchor, 1)
+    proc = _analyze_corrupted(bad, "party.py")
     assert proc.returncode == 1
     assert "SEC001" in proc.stdout
 
 
 def test_corrupted_protocol_dropped_reduction_is_flagged():
-    """Removing the `% field.P` before the int32 narrow in _decode_vec
-    -> FLD002."""
-    src = _protocol_source()
+    """Removing the `% field.P` before the int32 narrow in
+    party.decode_row -> FLD002."""
+    src = _core_source("party.py")
     anchor = "(dmat.sum(axis=0) % field.P).astype(np.int32)"
-    assert anchor in src, "protocol.py changed; update the corruption drill"
+    assert anchor in src, "party.py changed; update the corruption drill"
     bad = src.replace(anchor, "dmat.sum(axis=0).astype(np.int32)", 1)
-    proc = _analyze_corrupted(bad)
+    proc = _analyze_corrupted(bad, "party.py")
     assert proc.returncode == 1
     assert "FLD002" in proc.stdout
 
 
 def test_uncorrupted_protocol_copy_is_clean():
     """The drill harness itself must not produce findings on the pristine
-    file (otherwise the corruption assertions prove nothing)."""
-    proc = _analyze_corrupted(_protocol_source())
-    assert proc.returncode == 0, proc.stdout + proc.stderr
+    files (otherwise the corruption assertions prove nothing)."""
+    for name in ("protocol.py", "party.py"):
+        proc = _analyze_corrupted(_core_source(name), name)
+        assert proc.returncode == 0, (name, proc.stdout + proc.stderr)
 
 
 # ------------------------------------------------------------ property: FLD

@@ -53,8 +53,9 @@ the 128-lane axis and the per-class epilogue works on lane-dense (N, d)
 slabs, tiled over d in dc-wide chunks.  Per-client scalars (adv_off,
 dfull) and the ghat coefficients live in SMEM; the open row rvec is read
 as a (1, N) vector and stays in VMEM.  d need not be a multiple of dc (the
-chunk loops take a ragged tail); m is padded to bm with zero rows by ops.py
-(zero rows contribute zero to X~^T g).  VMEM budget: the six epilogue
+chunk loops take a ragged tail), nor m of bm (the row step of
+kernels/coded_gradient.py zeroes g past m in the edge block, so those
+rows add nothing to X~^T g).  VMEM budget: the six epilogue
 planes stay resident, about 6 * C * N * d * 4 bytes after (8, 128) tile
 padding -- 4.3 MB at the paper's N=50, d=3073, C=1.
 """
@@ -69,14 +70,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core import field
-from .coded_gradient import (DEFAULT_BM, DEFAULT_DC, _limb_dot_mod,
-                             accumulate_rows, chunks)
+from .coded_gradient import (_limb_dot_mod, accumulate_rows, chunks,
+                             pack_model, scratch, vmem_limit)
 
 
 def _kernel(x_ref, w_ref, c_ref, adv_ref, dfull_ref, rvec_ref, base_ref,
             xty_ref, wsh_ref, radd_ref, r0sh_ref, f_ref, wout_ref,
-            common_ref, *, degree: int, dc: int, q_eta: int, inv2k1: int,
-            k1: int):
+            common_ref, xl_ref, wp_ref, *, m: int, degree: int, dc: int,
+            q_eta: int, inv2k1: int, k1: int):
     n = pl.program_id(0)                # client (outer)
     i = pl.program_id(1)                # row block (innermost)
     last_blk = i == pl.num_programs(1) - 1
@@ -88,8 +89,10 @@ def _kernel(x_ref, w_ref, c_ref, adv_ref, dfull_ref, rvec_ref, base_ref,
     @pl.when(i == 0)
     def _init_f():
         f_ref[...] = jnp.zeros_like(f_ref)
+        pack_model(w_ref, wp_ref)
 
-    accumulate_rows(x_ref, w_ref, c_ref, f_ref, degree=degree, dc=dc)
+    accumulate_rows(x_ref, wp_ref, xl_ref, c_ref, f_ref, m=m, degree=degree,
+                    dc=dc)
 
     @pl.when(last_blk)
     def _fold_client():
@@ -111,7 +114,7 @@ def _kernel(x_ref, w_ref, c_ref, adv_ref, dfull_ref, rvec_ref, base_ref,
                     field.sub(xtg, xty_ref[c, :, s:e]), q_eta)
                 c_sh = field.add(scaled, radd_ref[c, :, s:e])
                 # masked OPEN: Lagrange row over holders (contraction N)
-                c_open = _limb_dot_mod(rvec_ref[...], c_sh, 1, 0)  # (1, e-s)
+                c_open = _limb_dot_mod(rvec_ref[...], c_sh)  # (1, e - s)
                 c0 = jnp.bitwise_and(c_open, (1 << k1) - 1)
                 a0 = field.sub(c0, r0sh_ref[c, :, s:e])
                 delta = field.mul_scalar(field.sub(scaled, a0), inv2k1)
@@ -123,8 +126,7 @@ def _kernel(x_ref, w_ref, c_ref, adv_ref, dfull_ref, rvec_ref, base_ref,
     static_argnames=("bm", "dc", "q_eta", "inv2k1", "k1", "interpret"))
 def fused_step(x, w, coeffs, adv_off, dfull, rvec, base, xty, wsh, radd,
                r0sh, *, q_eta: int, inv2k1: int, k1: int,
-               bm: int = DEFAULT_BM, dc: int = DEFAULT_DC,
-               interpret: bool = False):
+               bm: int, dc: int, interpret: bool = False):
     """One COPML GD step (post model-encode) as a single pallas_call.
 
     x: (N, m, d) coded slices; w: (N, C, d) class-major coded models;
@@ -132,24 +134,25 @@ def fused_step(x, w, coeffs, adv_off, dfull, rvec, base, xty, wsh, radd,
     decode row; rvec: (1, N) open row.  base/xty/wsh/radd/r0sh: (C, N, d)
     epilogue planes (see module docstring).  Returns (f, new_w): the
     per-client coded gradients (N, C, d) (pre-corruption, matching the
-    gradient kernel) and the updated model shares (C, N, d).  m % bm == 0
-    (ops.py pads); N <= 1024 bounds the open contraction; d may be ragged
-    w.r.t. dc.
+    gradient kernel) and the updated model shares (C, N, d).  The last row
+    block may be an edge block; N <= 1024 bounds the open contraction; d
+    may be ragged w.r.t. dc.
     """
     nb, m, d = x.shape
     c = w.shape[1]
     assert w.shape == (nb, c, d), (x.shape, w.shape)
-    assert m % bm == 0, (x.shape, bm)
+    bm = min(bm, m)
     assert bm <= 1024 and dc <= 1024 and nb <= 1024
+    assert bm == m or bm % 8 == 0, (m, bm)
     assert rvec.shape == (1, nb), rvec.shape
     for arr in (base, xty, wsh, radd, r0sh):
         assert arr.shape == (c, nb, d), (arr.shape, (c, nb, d))
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     whole = pl.BlockSpec((c, nb, d), lambda n, i: (0, 0, 0))
     return pl.pallas_call(
-        functools.partial(_kernel, degree=coeffs.shape[0] - 1, dc=dc,
+        functools.partial(_kernel, m=m, degree=coeffs.shape[0] - 1, dc=dc,
                           q_eta=q_eta, inv2k1=inv2k1, k1=k1),
-        grid=(nb, m // bm),
+        grid=(nb, pl.cdiv(m, bm)),
         in_specs=[
             pl.BlockSpec((1, bm, d), lambda n, i: (n, i, 0)),
             pl.BlockSpec((1, c, d), lambda n, i: (n, 0, 0)),
@@ -165,6 +168,9 @@ def fused_step(x, w, coeffs, adv_off, dfull, rvec, base, xty, wsh, radd,
             jax.ShapeDtypeStruct((nb, c, d), jnp.int32),    # f
             jax.ShapeDtypeStruct((c, nb, d), jnp.int32),    # new_w
         ],
-        scratch_shapes=[pltpu.VMEM((c, d), jnp.int32)],     # common
+        scratch_shapes=[pltpu.VMEM((c, d), jnp.int32)]     # common
+        + scratch(bm, d, c),
+        # the epilogue planes, double-buffered, and the common row
+        compiler_params=vmem_limit(bm, d, c, extra=4 * 12 * c * nb * d),
         interpret=interpret,
     )(x, w, coeffs, adv_off, dfull, rvec, base, xty, wsh, radd, r0sh)

@@ -1,11 +1,17 @@
 """Public wrappers for the Pallas kernels: padding, layout and dispatch.
 
 Each entry point runs the pure-jnp reference (kernels/ref.py) unless
-REPRO_USE_PALLAS=1 or the caller forces the kernel.  A kernel runs in
-interpret mode exactly when the default backend is the CPU (the body
-executes as written, so the tests verify its logic there) and is compiled
-by Mosaic everywhere else; a kernel that Mosaic refuses raises -- nothing
-falls back to the reference behind the caller's back.
+REPRO_USE_PALLAS=1 or the caller forces the kernel.  Phase 3's coded
+gradient (coded_gradient*, and the gradient inside fused_step's
+phase-by-phase composition) also takes its one-pass kernel on a TPU
+wherever `coded_gradient.routes` admits the shape, under the scope
+`field.coded_gradient`, with the blocks of `coded_gradient.blocks` (the
+REPRO_PALLAS_BLOCKS override and kernels/blocks.json steer only the
+kernels that are asked for); the megakernel stays behind the knob.  A kernel
+runs in interpret mode exactly when the default backend is the CPU (the
+body executes as written, so the tests verify its logic there) and is
+compiled by Mosaic everywhere else; a kernel that Mosaic refuses raises --
+nothing falls back to the reference behind the caller's back.
 """
 
 from __future__ import annotations
@@ -21,6 +27,7 @@ from . import field_poly as _fp
 from . import fused_step as _fs
 from . import modmatmul as _mm
 from . import ref
+from ..core import field
 from ..core.labels import Coded, Public
 
 # interpret-mode kernels are slow on CPU; route big shapes only when asked
@@ -73,17 +80,8 @@ def block_key(m: int, d: int, c: int = 1) -> str:
 
 
 def pick_blocks(m: int, d: int, c: int = 1) -> tuple[int, int]:
-    """(bm, dc) for an (m, d, C) gradient-family shape.
-
-    The fallback derives minima from the ACTUAL shape including the class
-    width: the matrix path's VMEM block holds (bm, d) of X~ plus the
-    (dc, C) output slice, so dc is shrunk when C is wide instead of
-    reusing the vector-path minimum (which padded ragged class-batched
-    shapes pathologically -- see the (m=13, C=10) regression test).  bm
-    shrinks for wide d: the double-buffered (bm, d) X~ block is capped at
-    2^20 elements (4 MiB a buffer), which with the limb temporaries keeps
-    the kernel inside Mosaic's 16 MiB scoped VMEM (d = 5000 needs bm 128).
-    """
+    """(bm, dc) for an (m, d, C) gradient-family shape: the override, then
+    the table, then the shape rule `coded_gradient.blocks`."""
     env = os.environ.get("REPRO_PALLAS_BLOCKS", "")
     if env:
         bm_s, dc_s = env.split(",")
@@ -91,13 +89,7 @@ def pick_blocks(m: int, d: int, c: int = 1) -> tuple[int, int]:
     entry = _block_table().get(block_key(m, d, c))
     if entry:
         return int(entry["bm"]), int(entry["dc"])
-    bm = min(_cg.DEFAULT_BM, _bucket(m))
-    while bm > 8 and bm * _bucket(d) > (1 << 20):
-        bm //= 2
-    dc = min(_cg.DEFAULT_DC, _bucket(d))
-    while c > 1 and dc * _bucket(c) > 16384 and dc > 8:
-        dc //= 2
-    return bm, dc
+    return _cg.blocks(m, d, c)
 
 
 def _pad_to(x, axis, mult):
@@ -167,27 +159,42 @@ def poly_eval(z, coeffs, *, block=None, force_pallas: bool = False):
     return out.reshape(shape)
 
 
-def _gradient_rows(x, w_rows, coeffs, bm, dc):
-    """The gradient kernel on class-major models w_rows: (N, C, d), with
-    m padded to bm and d to dc (zero rows/columns contribute nothing)."""
-    d0 = x.shape[2]
-    tbm, tdc = pick_blocks(x.shape[1], d0, w_rows.shape[1])
-    bm = bm or tbm
-    dc = dc or tdc
-    x, _ = _pad_to(x, 1, bm)
-    x, _ = _pad_to(x, 2, dc)
-    w_rows, _ = _pad_to(w_rows, 2, dc)
-    out = _cg.coded_gradient(x, w_rows, coeffs, bm=bm, dc=dc,
-                             interpret=interpret_mode())
-    return out[:, :, :d0]
+def _routes(shape, c: int) -> bool:
+    """Whether Phase 3 on coded slices of `shape` (N, m, d) with C classes
+    takes the one-pass kernel by shape: on a TPU, where
+    `coded_gradient.routes` admits it."""
+    return field._tpu_backend() and _cg.routes(shape[1], c)
+
+
+def _gradient_blocks(shape, c: int, force: bool):
+    """(bm, dc) of Phase 3's kernel on coded slices of `shape` (N, m, d)
+    with C classes, or None where the jnp path runs.  Asked for
+    (REPRO_USE_PALLAS, force_pallas), the blocks come from pick_blocks, its
+    override and table included; chosen by shape, from
+    `coded_gradient.blocks` alone."""
+    if USE_PALLAS or force:
+        return pick_blocks(shape[1], shape[2], c)
+    if _routes(shape, c):
+        return _cg.blocks(shape[1], shape[2], c)
+    return None
+
+
+def _gradient_rows(x, w_rows, coeffs, blk, bm, dc):
+    """The gradient kernel on class-major models w_rows: (N, C, d), x read
+    as stored (the last row block an edge block, d ragged); bm, dc override
+    the blocks `blk`."""
+    with jax.named_scope("field.coded_gradient"):
+        return _cg.coded_gradient(x, w_rows, coeffs, bm=bm or blk[0],
+                                  dc=dc or blk[1], interpret=interpret_mode())
 
 
 def coded_gradient(x: Coded, w: Coded, coeffs: Public, *, bm=None, dc=None,
                    force_pallas: bool = False) -> Coded:
     """Fused f = x^T ghat(x w) over F_p (COPML Eq. 7) for one client."""
-    if not (USE_PALLAS or force_pallas):
+    blk = _gradient_blocks((1,) + x.shape, 1, force_pallas)
+    if blk is None:
         return ref.coded_gradient(x, w, coeffs)
-    return _gradient_rows(x[None], w[None, None], coeffs, bm, dc)[0, 0]
+    return _gradient_rows(x[None], w[None, None], coeffs, blk, bm, dc)[0, 0]
 
 
 def coded_gradient_batched(x: Coded, w: Coded, coeffs: Public, *, bm=None,
@@ -197,9 +204,10 @@ def coded_gradient_batched(x: Coded, w: Coded, coeffs: Public, *, bm=None,
     x: (N, m, d); w: (N, d); coeffs shared.  This is COPML's whole Phase-3
     round (every client's Eq. 7 evaluation) as a single (N, m/bm) grid.
     """
-    if not (USE_PALLAS or force_pallas):
+    blk = _gradient_blocks(x.shape, 1, force_pallas)
+    if blk is None:
         return ref.coded_gradient_batched(x, w, coeffs)
-    return _gradient_rows(x, w[:, None], coeffs, bm, dc)[:, 0]
+    return _gradient_rows(x, w[:, None], coeffs, blk, bm, dc)[:, 0]
 
 
 def coded_gradient_matrix(x: Coded, w: Coded, coeffs: Public, *, bm=None,
@@ -210,9 +218,10 @@ def coded_gradient_matrix(x: Coded, w: Coded, coeffs: Public, *, bm=None,
     (N, m/bm)-grid launch computes every client's and every class's coded
     gradient as a batched GEMM pair, instead of C matvec dispatches.
     """
-    if not (USE_PALLAS or force_pallas):
+    blk = _gradient_blocks(x.shape, w.shape[2], force_pallas)
+    if blk is None:
         return ref.coded_gradient_matrix(x, w, coeffs)
-    f = _gradient_rows(x, jnp.swapaxes(w, 1, 2), coeffs, bm, dc)
+    f = _gradient_rows(x, jnp.swapaxes(w, 1, 2), coeffs, blk, bm, dc)
     return jnp.swapaxes(f, 1, 2)
 
 
@@ -224,18 +233,17 @@ def fused_step(x, w, coeffs, adv_off, dfull, rvec, base, xty, wsh, radd,
     Operands and returns in the reference layout (ref.fused_step): w and
     the epilogue planes (N, d, C), adv_off/dfull/rvec (N,).  Moves them to
     the kernel's class-major layout (kernels/fused_step.py; free reshapes
-    for C = 1) and pads only the sample axis m (zero rows are exact: they
-    contribute nothing to X~^T g); the kernel takes d ragged.  Runs the
-    phase-by-phase reference composition when Pallas is not requested.
+    for C = 1); the kernel reads x as stored.  When the megakernel is not
+    requested this is the phase-by-phase composition: the coded gradient
+    (coded_gradient_matrix, the Pallas gradient kernel where it routes)
+    and ref.fused_update.
     """
     if not (USE_PALLAS or force_pallas):
-        return ref.fused_step(x, w, coeffs, adv_off, dfull, rvec, base, xty,
-                              wsh, radd, r0sh, q_eta=q_eta, inv2k1=inv2k1,
-                              k1=k1)
+        f = coded_gradient_matrix(x, w, coeffs)
+        return f, ref.fused_update(f, adv_off, dfull, rvec, base, xty, wsh,
+                                   radd, r0sh, q_eta=q_eta, inv2k1=inv2k1,
+                                   k1=k1)
     tbm, tdc = pick_blocks(x.shape[1], x.shape[2], w.shape[2])
-    bm = bm or tbm
-    dc = dc or min(tdc, _bucket(x.shape[2]))
-    x, _ = _pad_to(x, 1, bm)
 
     def plane(a):                       # (N, d, C) -> (C, N, d)
         return jnp.transpose(a, (2, 0, 1))
@@ -243,6 +251,6 @@ def fused_step(x, w, coeffs, adv_off, dfull, rvec, base, xty, wsh, radd,
     f, new_w = _fs.fused_step(
         x, jnp.swapaxes(w, 1, 2), coeffs, adv_off, dfull, rvec[None],
         plane(base), plane(xty), plane(wsh), plane(radd), plane(r0sh),
-        q_eta=q_eta, inv2k1=inv2k1, k1=k1, bm=bm, dc=dc,
+        q_eta=q_eta, inv2k1=inv2k1, k1=k1, bm=bm or tbm, dc=dc or tdc,
         interpret=interpret_mode())
     return jnp.swapaxes(f, 1, 2), jnp.transpose(new_w, (1, 2, 0))

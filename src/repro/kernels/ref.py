@@ -70,15 +70,23 @@ def coded_gradient_matrix(x: Coded, w: Coded, coeffs: Public) -> Coded:
 
 def fused_step(x, w, coeffs, adv_off, dfull, rvec, base, xty, wsh, radd,
                r0sh, *, q_eta: int, inv2k1: int, k1: int):
-    """Phase-by-phase oracle for kernels.fused_step (same operands/returns).
-
-    Composes the existing references in protocol order: matrix coded
-    gradient, corruption offset, decode fold against the zero-scattered
-    decode row, q_eta scale, TruncPr masked open (rvec = the reconstruct
-    Lagrange row zero-padded over holders) and borrow-folded rescale.
-    """
-    n = x.shape[0]
+    """Phase-by-phase oracle for kernels.fused_step (same operands/returns):
+    the matrix coded gradient, then fused_update."""
     f = coded_gradient_matrix(x, w, coeffs)
+    return f, fused_update(f, adv_off, dfull, rvec, base, xty, wsh, radd,
+                           r0sh, q_eta=q_eta, inv2k1=inv2k1, k1=k1)
+
+
+def fused_update(f, adv_off, dfull, rvec, base, xty, wsh, radd, r0sh, *,
+                 q_eta: int, inv2k1: int, k1: int):
+    """Phase 4 of kernels.fused_step on the coded gradients f: (N, d, C).
+
+    Composes the existing references in protocol order: corruption offset,
+    decode fold against the zero-scattered decode row, q_eta scale,
+    TruncPr masked open (rvec = the reconstruct Lagrange row zero-padded
+    over holders) and borrow-folded rescale.  Returns the new model shares.
+    """
+    n = f.shape[0]
     f_adj = field.add(f, adv_off[:, None, None])
     common = field.matmul(
         dfull[None], f_adj.reshape(n, -1))[0].reshape(f.shape[1:])
@@ -91,4 +99,4 @@ def fused_step(x, w, coeffs, adv_off, dfull, rvec, base, xty, wsh, radd,
     c0 = jnp.bitwise_and(c_open, (1 << k1) - 1)
     a0 = field.sub(jnp.broadcast_to(c0[None], c_sh.shape), r0sh)
     delta = field.mul_scalar(field.sub(scaled, a0), inv2k1)
-    return f, field.sub(wsh, delta)
+    return field.sub(wsh, delta)

@@ -79,28 +79,42 @@ def _block_n(mp: int, kp: int, n: int) -> int:
     return max(_LANE, min(bn, _MAX_BN, _round_up(n, _LANE)))
 
 
+def limb_planes(x):
+    """The four 7-bit limbs of int32 field elements, as int32 arrays of
+    x's shape (each < 2^7, so int8 holds it exactly)."""
+    return [jnp.bitwise_and(jax.lax.shift_right_logical(x, 7 * i), 0x7F)
+            for i in range(_N_LIMBS)]
+
+
+def class_rows(limbs, j: int):
+    """Column block j of the weight-class packing of a's limbs, (7 M, K):
+    row block s holds the limb s - j, zero where s - j is no limb."""
+    zero = jnp.zeros_like(limbs[0])
+    return jnp.concatenate([limbs[s - j] if 0 <= s - j < _N_LIMBS else zero
+                            for s in range(_N_GROUPS)], axis=0)
+
+
+def recombine_classes(g, rows: int):
+    """(7 rows, N) int32 class sums, each < 2^26 -> (rows, N) mod p."""
+    return field.recombine_limb_groups(
+        [g[s * rows:(s + 1) * rows] for s in range(_N_GROUPS)])
+
+
 def _packed_limbs(a, mp: int, kp: int):
     """(7 mp, 4 kp) int8: row block s, column block j holds the limb
     s - j of `a` (zero-padded to (mp, kp)), zero where s - j is no limb."""
     m, k = a.shape
-    al = jnp.pad(field._limbs(a), ((0, 0), (0, mp - m), (0, kp - k)))
-    zero = jnp.zeros((mp, kp), al.dtype)
-    rows = [jnp.concatenate([al[s - j] if 0 <= s - j < _N_LIMBS else zero
-                             for j in range(_N_LIMBS)], axis=1)
-            for s in range(_N_GROUPS)]
-    return jnp.concatenate(rows, axis=0).astype(jnp.int8)
+    al = limb_planes(jnp.pad(a, ((0, mp - m), (0, kp - k))))
+    return jnp.concatenate([class_rows(al, j) for j in range(_N_LIMBS)],
+                           axis=1).astype(jnp.int8)
 
 
 def _kernel(lhs_ref, b_ref, o_ref):
-    b = b_ref[...]                                           # (kp, bn)
-    limbs = jnp.concatenate(
-        [jnp.bitwise_and(jax.lax.shift_right_logical(b, 7 * i), 0x7F)
-         for i in range(_N_LIMBS)], axis=0).astype(jnp.int8)
+    limbs = jnp.concatenate(limb_planes(b_ref[...]),
+                            axis=0).astype(jnp.int8)         # (4 kp, bn)
     g = jnp.dot(lhs_ref[...], limbs,
                 preferred_element_type=jnp.int32)            # (7 mp, bn)
-    mp = o_ref.shape[0]
-    o_ref[...] = field.recombine_limb_groups(
-        [g[s * mp:(s + 1) * mp] for s in range(_N_GROUPS)])
+    o_ref[...] = recombine_classes(g, o_ref.shape[0])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

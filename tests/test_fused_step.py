@@ -98,9 +98,27 @@ def test_pick_blocks_table_and_fallback(monkeypatch):
     monkeypatch.setattr(ops, "_block_table_cache",
                         {"m512_d32_c16": {"bm": 256, "dc": 16}})
     assert ops.pick_blocks(390, 24, 10) == (256, 16)
-    # fallback: no entry for this bucket; bm clamps to bucket(13) == 16
-    # and dc halves while dc * bucket(C) exceeds the VMEM budget
-    assert ops.pick_blocks(13, 512, 300) == (16, 32)
+    # fallback: no entry for this bucket; all 13 rows make one block and
+    # dc halves while dc * C (rounded up to 8) exceeds the VMEM budget
+    assert ops.pick_blocks(13, 512, 300) == (13, 32)
+
+
+def test_phase3_routed_by_shape_ignores_the_override_and_table(monkeypatch):
+    """Where the shape alone sends Phase 3 to its kernel (on a TPU), the
+    blocks are `coded_gradient.blocks`' own: neither REPRO_PALLAS_BLOCKS
+    nor kernels/blocks.json reaches that path; a kernel asked for reads
+    them."""
+    from repro.kernels import coded_gradient as cg
+    monkeypatch.setenv("REPRO_PALLAS_BLOCKS", "64,32")
+    monkeypatch.setattr(ops, "_block_table_cache",
+                        {"m512_d32_c16": {"bm": 256, "dc": 16}})
+    monkeypatch.setattr(ops, "_routes", lambda shape, c: True)
+    for shape in [(50, 902, 3073), (4, 390, 24)]:
+        assert ops._gradient_blocks(shape, 1, False) == cg.blocks(
+            shape[1], shape[2], 1)
+        assert ops._gradient_blocks(shape, 1, True) == (64, 32)
+    monkeypatch.setattr(ops, "_routes", lambda shape, c: False)
+    assert ops._gradient_blocks((4, 390, 24), 1, False) is None
 
 
 def test_coded_gradient_matrix_ragged_regression():

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import field as F
 from repro.kernels import coded_gradient as cgk
@@ -149,6 +150,63 @@ def test_short_modmatmul_matches_field_matmul(rng, m, k):
     for i in range(2):
         np.testing.assert_array_equal(
             got[i], F.np_matmul(np.asarray(a), np.asarray(bs[i])))
+
+
+def _np_coded_gradient(x, w, c):
+    """uint64 oracle of x^T ghat(x @ w) for one client, w: (d, C)."""
+    z = F.np_matmul(x, w)
+    g = np.zeros_like(z)
+    for ci in reversed(np.asarray(c).astype(np.int64)):
+        g = (g * z + ci) % F.P
+    return F.np_matmul(x.T, g.astype(np.int32))
+
+
+@pytest.mark.parametrize("nb,m,d,c,bm,dc", [
+    (1, 20, 37, 1, 8, 16),      # edge block of 4 rows, ragged d and chunk
+    (5, 20, 37, 1, 8, 16),
+    (1, 13, 300, 3, 8, 128),
+    (5, 13, 300, 3, 8, 128),
+    (5, 40, 130, 3, 40, 1024),  # one block of all rows, d < dc
+])
+def test_one_pass_coded_gradient_matches_oracles(rng, nb, m, d, c, bm, dc):
+    """The one-pass kernel equals the jnp path and the uint64 oracle for
+    every client: m not a multiple of bm leaves an edge block, whose rows
+    past m the TPU interpreter reads as int32 max (every limb 127, as a
+    chip's stale VMEM might hold), and d is no multiple of dc or 128."""
+    x = rng.integers(0, F.P, size=(nb, m, d)).astype(np.int32)
+    w = rng.integers(0, F.P, size=(nb, d, c)).astype(np.int32)
+    co = jnp.asarray(rng.integers(0, F.P, size=(3,)).astype(np.int32))
+    got = np.asarray(cgk.coded_gradient(
+        jnp.asarray(x), jnp.swapaxes(jnp.asarray(w), 1, 2), co, bm=bm,
+        dc=dc, interpret=pltpu.InterpretParams(
+            out_of_bounds_reads="uninitialized")))
+    np.testing.assert_array_equal(
+        np.swapaxes(got, 1, 2),
+        np.asarray(ref.coded_gradient_matrix(jnp.asarray(x), jnp.asarray(w),
+                                             co)))
+    for i in range(nb):
+        np.testing.assert_array_equal(got[i].T,
+                                      _np_coded_gradient(x[i], w[i], co))
+
+
+@pytest.mark.parametrize("coeffs", [(0, 1), (F.P - 1, 0)],
+                         ids=["g_is_z", "g_is_p_minus_1"])
+def test_one_pass_coded_gradient_extreme(coeffs):
+    """Every element p - 1 at d = 3073 in one 1024-row block: both
+    contractions at their longest (1024), so the class sums reach their
+    largest, which must stay below 2^26 for the one Barrett reduce."""
+    m, d = F.MATMUL_CHUNK, 3073
+    x = np.full((1, m, d), F.P - 1, np.int32)
+    w = np.full((1, d, 1), F.P - 1, np.int32)
+    co = jnp.asarray(np.asarray(coeffs, np.int32))
+    got = np.asarray(cgk.coded_gradient(
+        jnp.asarray(x), jnp.swapaxes(jnp.asarray(w), 1, 2), co, bm=m,
+        dc=F.MATMUL_CHUNK, interpret=True))
+    np.testing.assert_array_equal(got[0].T, _np_coded_gradient(x[0], w[0],
+                                                               co))
+    np.testing.assert_array_equal(
+        got[0].T, np.asarray(ref.coded_gradient_matrix(
+            jnp.asarray(x), jnp.asarray(w), co))[0])
 
 
 def test_matvec_batched_extreme(rng):

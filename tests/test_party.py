@@ -203,3 +203,37 @@ def test_siloed_schedule_matches_fused_fit(monkeypatch, own_copml_drivers):
         out[mode] = (res.weights, np.asarray(res.state.w_shares))
     np.testing.assert_array_equal(out["0"][0], out["1"][0])
     np.testing.assert_array_equal(out["0"][1], out["1"][1])
+
+
+@pytest.mark.parametrize("mode", ["0", "1"])
+def test_one_pass_gradient_kernel_matches_default_fit(monkeypatch,
+                                                      own_copml_drivers,
+                                                      mode):
+    """Phase 3 forced onto the one-pass Pallas kernel (interpreted here),
+    as `ops` routes it on a TPU, in both schedules (ops.fused_step's
+    composition under "1", local_gradient under "0"): the fit's weights
+    and shares equal the default path's bit for bit, and the shares hash
+    to the pinned golden."""
+    import hashlib
+
+    from goldens import GOLDEN_SHARES_SHA
+    from repro.kernels import ops
+
+    monkeypatch.setenv("REPRO_FUSED_STEP", mode)
+    out = {}
+    for forced in (False, True):
+        routed = []
+        if forced:
+            monkeypatch.setattr(ops, "_routes",
+                                lambda shape, c: routed.append(shape)
+                                or True)
+        jax.clear_caches()
+        monkeypatch.setattr(api.PROTOCOLS["copml"], "_drivers", {})
+        res = api.fit("smoke", "copml", "jit", key=0, iters=10,
+                      history=False)
+        assert bool(routed) == forced
+        out[forced] = (res.weights, np.asarray(res.state.w_shares, np.int32))
+    np.testing.assert_array_equal(out[True][0], out[False][0])
+    np.testing.assert_array_equal(out[True][1], out[False][1])
+    assert hashlib.sha256(out[True][1].tobytes()).hexdigest() == \
+        GOLDEN_SHARES_SHA

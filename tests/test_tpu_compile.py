@@ -153,6 +153,34 @@ def test_field_matmul_chooses_by_shape(one_chip, a_shape, b_shape, batch,
     assert ("tpu_custom_call" in txt) == kernel
 
 
+def _holds_kernel(hlo_text: str, name: str) -> bool:
+    """Whether a Mosaic call named after `name` is in the compiled text."""
+    return any(f"%{name}" in line and "tpu_custom_call" in line
+               for line in hlo_text.splitlines())
+
+
+# Phase 3 at the cells' and registered workloads' shapes, per client
+# (m, d, C) over N clients, and whether it takes the one-pass kernel.
+@pytest.mark.parametrize("n,m,d,c,kernel", [
+    (50, 902, 3073, 1, True),     # cifar10_case2 at the paper's m
+    (50, 564, 3073, 1, True),     # cifar10_case1 at the paper's m
+    (50, 8, 3073, 1, False),      # the m/K = 8 cells
+    (50, 375, 5000, 1, True),     # gisette_case1: wide d
+    (13, 98, 24, 10, False),      # mnist10_like: class-batched, unmeasured
+], ids=["case2_full", "case1_full", "m_over_k_8", "gisette", "mnist10"])
+def test_phase3_chooses_by_shape(one_chip, n, m, d, c, kernel):
+    from repro.kernels import coded_gradient
+    assert coded_gradient.routes(m, c) == kernel
+    txt = _compile_text(ops.coded_gradient_matrix, one_chip, (n, m, d),
+                        (n, d, c), (2,))
+    assert _holds_kernel(txt, "coded_gradient") == kernel
+    assert ("tpu_custom_call" in txt) == kernel
+    if c == 1:
+        txt = _compile_text(ops.coded_gradient_batched, one_chip,
+                            (n, m, d), (n, d), (2,))
+        assert _holds_kernel(txt, "coded_gradient") == kernel
+
+
 # The paper's CIFAR-10 Case 2 at full m on one 16 GB v5e.  Setup's compiled
 # temporaries and the X~ it leaves behind take at most SETUP_BUDGET; the
 # loop program, which runs after setup's temporaries are freed, holds X~
@@ -183,9 +211,15 @@ def test_full_size_case2_setup_and_loop_fit_one_chip(one_chip):
     state = protocol.CopmlState(
         w_shares=shape((n, d)), coded_x=shape((n, mk, d)),
         xty_shares=shape((n, d)), step=shape(()))
-    loop = protocol._scan_iterations.lower(
+    compiled = protocol._scan_iterations.lower(
         protocol.Copml(cfg, m, d), key, state, 50, None, False,
-        None).compile().memory_analysis()
+        None).compile()
+    assert _holds_kernel(compiled.as_text(), "coded_gradient")
+    loop = compiled.memory_analysis()
     held = loop.temp_size_in_bytes + loop.argument_size_in_bytes + \
         loop.output_size_in_bytes
     assert held <= CHIP_BYTES - SETUP_BUDGET, held
+    # Phase 3 reads X~ as stored: no limb plane, transposed or padded copy
+    # of it among the loop's temporaries
+    assert loop.temp_size_in_bytes < xtilde, (loop.temp_size_in_bytes,
+                                              xtilde)
